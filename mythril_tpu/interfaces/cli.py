@@ -1153,6 +1153,15 @@ def parse_args_and_execute(parser: argparse.ArgumentParser, args: argparse.Names
             getattr(args, "lane_ledger_out", None),
         )
 
+    if args.command in ANALYZE_LIST or args.command in (
+        "truffle", "serve", "watch",
+    ):
+        # the persistent compile cache must be in place before the
+        # first jit of any command that compiles
+        from mythril_tpu.ops import configure_jax
+
+        configure_jax()
+
     if args.command == "serve":
         # serve-plane knobs are env-validated at startup: a typo'd
         # MYTHRIL_TPU_SERVE_* value must die loudly here (exit 2, the

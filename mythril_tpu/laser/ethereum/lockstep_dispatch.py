@@ -146,13 +146,13 @@ def _validate_on_lockstep(code_hex: str, plan: DispatcherPlan):
     """One concrete lane per selector through the SoA interpreter; the
     mapped entry's visited-pc bit must be set for every lane.  Returns
     True/False for a real verdict, or None when validation could not
-    run (unhealthy device) — the caller must NOT cache None-by-health,
-    so the pre-split re-attempts after the accelerator recovers."""
+    run (demoted device) — the caller must NOT cache None-by-health."""
     from mythril_tpu.ops import lockstep
-    from mythril_tpu.ops.device_health import device_ok
+    from mythril_tpu.ops.device_health import count_skip, device_ok
 
     if not device_ok():
-        return None  # never risk a wedged accelerator mid-analysis
+        count_skip("lockstep pre-split validation")
+        return None
     try:
         code = bytes.fromhex(code_hex.removeprefix("0x"))
         selectors = sorted(plan.branches)

@@ -1,36 +1,74 @@
 """Native components: CDCL SAT solver + fast keccak, built from C++ at
-first import (g++ is in the image; no prebuilt wheels are shipped).
+first import (g++ is in the image; no prebuilt binary is committed).
 
-The compiled library is cached next to the sources; rebuilds happen only
-when the source is newer than the binary.
+The compiled library is cached next to the sources under a name that
+carries a hash of the sources and the compiler flags: an edit to
+either builds a new library, and an unchanged tree reuses the cached
+one whatever the file mtimes say (a copied tree keeps its mtimes, not
+its provenance).
 """
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
+import tempfile
 import threading
 from typing import List, Optional, Sequence
 
 log = logging.getLogger(__name__)
 
 _SRC_DIR = os.path.join(os.path.dirname(__file__), "csrc")
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "_native.so")
+_CXXFLAGS = ("-O2", "-std=c++17", "-shared", "-fPIC")
 _build_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 
 
-def _build() -> None:
-    sources = [
-        os.path.join(_SRC_DIR, name)
-        for name in sorted(os.listdir(_SRC_DIR))
-        if name.endswith(".cpp")
-    ]
-    cmd = [
-        "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", _LIB_PATH,
-    ] + sources
+def lib_path(src_dir: str = _SRC_DIR) -> str:
+    """Where the library built from ``src_dir``'s ``*.cpp`` lives: one
+    file per content hash of the sources and flags."""
+    digest = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    for name in sorted(os.listdir(src_dir)):
+        if name.endswith(".cpp"):
+            digest.update(name.encode())
+            with open(os.path.join(src_dir, name), "rb") as fh:
+                digest.update(fh.read())
+    return os.path.join(
+        os.path.dirname(src_dir), f"_native.{digest.hexdigest()[:16]}.so"
+    )
+
+
+def build(src_dir: str = _SRC_DIR) -> str:
+    """Return the library for the current sources, compiling it first
+    when no library of this hash exists.  The compile writes a temp
+    file and renames it into place, so concurrent builders (test
+    workers) never load a half-written library; libraries of older
+    hashes are removed."""
+    path = lib_path(src_dir)
+    if os.path.exists(path):
+        return path
+    sources = sorted(glob.glob(os.path.join(src_dir, "*.cpp")))
+    fd, tmp = tempfile.mkstemp(
+        prefix="_native.", suffix=".so.tmp", dir=os.path.dirname(path)
+    )
+    os.close(fd)
+    cmd = ["g++", *_CXXFLAGS, "-o", tmp] + sources
     log.info("building native library: %s", " ".join(cmd))
-    subprocess.run(cmd, check=True, capture_output=True)
+    try:
+        subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, path)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    for stale in glob.glob(os.path.join(os.path.dirname(path), "_native*.so")):
+        if stale != path:
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass  # another process may have removed it already
+    return path
 
 
 def load() -> ctypes.CDLL:
@@ -40,14 +78,7 @@ def load() -> ctypes.CDLL:
     with _build_lock:
         if _lib is not None:
             return _lib
-        newest_src = max(
-            os.path.getmtime(os.path.join(_SRC_DIR, n))
-            for n in os.listdir(_SRC_DIR)
-            if n.endswith(".cpp")
-        )
-        if not os.path.exists(_LIB_PATH) or os.path.getmtime(_LIB_PATH) < newest_src:
-            _build()
-        lib = ctypes.CDLL(_LIB_PATH)
+        lib = ctypes.CDLL(build())
         lib.cdcl_new.restype = ctypes.c_void_p
         lib.cdcl_free.argtypes = [ctypes.c_void_p]
         lib.cdcl_new_var.argtypes = [ctypes.c_void_p]

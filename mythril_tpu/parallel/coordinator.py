@@ -579,6 +579,12 @@ class Coordinator:
                                 sys.executable)
         env = dict(os.environ)
         env["MYTHRIL_TPU_FLEET_ROLE"] = "worker"
+        # one process per chip: the coordinator has already run the
+        # first transaction (and may hold the chip), so a local worker
+        # that reached for it would fail or hang — local workers solve
+        # on the CPU (native CDCL).  A remote `myth worker` on its own
+        # host is not spawned here and keeps its own device.
+        env["JAX_PLATFORMS"] = "cpu"
         env["MYTHRIL_TPU_CHECKPOINT_PERIOD"] = (
             self.config.checkpoint_period_s
         )

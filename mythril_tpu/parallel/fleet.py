@@ -993,6 +993,9 @@ def worker_main(argv: Optional[List[str]] = None) -> int:
     retries = (opts.reconnect if opts.reconnect is not None
                else env_int("MYTHRIL_TPU_FLEET_RECONNECT", 0, floor=0))
     checkpoint.install_signal_handlers()
+    from mythril_tpu.ops import configure_jax
+
+    configure_jax()
     # knowledge store: load once at seat start (warm leases from the
     # first one) — inert without MYTHRIL_TPU_PERSIST_DIR
     from mythril_tpu.persist.plane import get_knowledge_plane

@@ -96,10 +96,7 @@ def make_sharded_solve(mesh, num_vars: int):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from mythril_tpu.ops.batched_sat import build_solve_lane
 
@@ -129,11 +126,21 @@ def make_sharded_solve(mesh, num_vars: int):
         in_specs=(P("cp", None), P("dp", None)),
         out_specs=(P("dp", None), P("dp")),
     )
-    try:  # jax >= 0.8 renamed the replication-check toggle
-        sharded = shard_map(solve_shard, check_vma=False, **specs)
-    except TypeError:  # pragma: no cover — older jax
-        sharded = shard_map(solve_shard, check_rep=False, **specs)
-    return jax.jit(sharded)
+    return jax.jit(shard_map(solve_shard, check_vma=False, **specs))
+
+
+def place_on_mesh(mesh, lits: np.ndarray, assign: np.ndarray):
+    """Put the clause rows (sharded over ``cp``) and the lane
+    assignments (over ``dp``) straight onto their devices: each device
+    receives only its shard, none stages the whole batch."""
+    import jax
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    return (
+        jax.device_put(lits, NamedSharding(mesh, P("cp", None))),
+        jax.device_put(assign, NamedSharding(mesh, P("dp", None))),
+    )
 
 
 def sharded_frontier_solve(
@@ -141,9 +148,6 @@ def sharded_frontier_solve(
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Solve a frontier batch on the mesh; pads lanes to the dp size and
     clause rows to the cp size."""
-    import jax
-    import jax.numpy as jnp
-
     dp = mesh.shape["dp"]
     cp = mesh.shape["cp"]
     batch = assign.shape[0]
@@ -165,7 +169,11 @@ def sharded_frontier_solve(
         assign = np.concatenate(
             [assign, np.ones((batch, v1 - true_v1), np.int8)], axis=1
         )
-    pad_lanes = (-batch) % dp
+    # lanes pad to their power-of-two bucket (then to a multiple of
+    # dp): every distinct width would otherwise compile its own solve
+    from mythril_tpu.ops.batched_sat import lane_bucket
+
+    pad_lanes = -(-lane_bucket(batch) // dp) * dp - batch
     if pad_lanes:
         # pad lanes fully assigned: an all-open lane would keep the
         # data-dependent DPLL loop (and its per-sweep psum) running a
@@ -184,9 +192,7 @@ def sharded_frontier_solve(
         solve = make_sharded_solve(mesh, num_vars)
         _solve_cache.clear()  # one live shape per mesh is enough
         _solve_cache[cache_key] = solve
-    final_assign, status = solve(
-        jnp.asarray(lits), jnp.asarray(assign)
-    )
+    final_assign, status = solve(*place_on_mesh(mesh, lits, assign))
     return (
         np.asarray(final_assign)[:batch, :true_v1],
         np.asarray(status)[:batch],

@@ -537,7 +537,7 @@ class CheckpointPlane:
 
         payload = dict(self._boundary)
         payload["channels"] = freeze_channels(get_blast_context())
-        payload["device_verdict"] = device_health._verdict
+        payload["device_demoted"] = device_health.demotion()
         payload["stats"] = {
             "dispatch": {
                 k: v for k, v in dispatch_stats.__dict__.items()
@@ -655,8 +655,8 @@ class CheckpointPlane:
         from mythril_tpu.ops.batched_sat import reset_resident_pools
 
         reset_resident_pools()
-        if payload.get("device_verdict") is False:
-            device_health._verdict = False
+        if payload.get("device_demoted"):
+            device_health.mark_unhealthy(payload["device_demoted"])
         resumed_stats = payload.get("stats", {}).get("resilience", {})
         for key, value in resumed_stats.items():
             if hasattr(resilience_stats, key):

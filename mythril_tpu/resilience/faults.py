@@ -2,14 +2,14 @@
 
 Every partial-failure seam in the system consults this plane through a
 named injection point, so every degradation path — watchdog trip,
-retry, re-probe, context/process demotion, RPC backoff — is testable
+retry, context/process demotion, RPC backoff — is testable
 on a CPU-only host with no real hardware failing.
 
 Injection points:
 
 ==================  =====================================================
 ``dispatch_hang``    a device dispatch blocks for ``hang_s`` seconds and
-                     then dies (models a wedged tunnel the runtime never
+                     then dies (models a kernel the runtime never
                      returns from; the watchdog must trip first)
 ``dispatch_error``   a device dispatch raises (stands in for
                      ``XlaRuntimeError`` — the retry rung's territory)
@@ -20,7 +20,7 @@ Injection points:
                      path — device UNSAT soundness is a kernel contract,
                      not something garbage can silently forge into
                      findings)
-``probe_flap``       the health probe flips healthy → dead mid-run
+``probe_flap``       the device verdict flips healthy → demoted mid-run
                      (``device_ok()`` starts answering False)
 ``cdcl_error``       the native CDCL raises on solve (the authoritative
                      tail's own retry rung)
@@ -373,8 +373,8 @@ def maybe_corrupt_lanes(status: np.ndarray, assign: np.ndarray):
 
 
 def health_flap() -> bool:
-    """Health-probe seam: True when ``probe_flap`` fires — the caller
-    (ops/device_health.py) flips its cached verdict to dead."""
+    """Device-verdict seam: True when ``probe_flap`` fires — the caller
+    (ops/device_health.py) demotes the process."""
     return get_fault_plane().fire("probe_flap") is not None
 
 

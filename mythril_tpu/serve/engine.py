@@ -170,14 +170,10 @@ class AnalysisEngine:
         }
 
     def degraded(self) -> bool:
-        """True when the device was demoted (cached verdict only — a
-        readiness probe must never trigger a cold device probe)."""
+        """True when the device was demoted (fires no fault point)."""
         from mythril_tpu.ops import device_health
 
-        return (
-            device_health.probe_completed()
-            and not device_health.device_ok()
-        )
+        return device_health.demotion() is not None
 
     def mode(self) -> str:
         return "host-cdcl" if self.degraded() else "device"

@@ -361,26 +361,20 @@ class WordTier:
           dispatches by ~3 orders of magnitude;
         - numpy: the batched limb-plane kernels on host (parity
           testing / very wide frontiers);
-        - jax.numpy: the batched limb-plane kernels on device — wide
-          dispatch frontiers ride the accelerator exactly like the
-          lockstep stepper's word planes.
+        - jax.numpy: the same kernels run eagerly, op by op — only
+          when forced.  Each op is its own device dispatch (and its own
+          compile per shape), so this path never pays: the depth-6
+          scale contract at ``-t 1`` took 315 s and hit its execution
+          timeout with no findings, against 9 s scalar (CPU, PR 21),
+          and on a v5e it was the auto policy for frontiers of 16+
+          lanes until PR 21.
 
         ``MYTHRIL_TPU_WORD_XP=scalar|numpy|jax`` overrides the policy.
         """
         forced = os.environ.get("MYTHRIL_TPU_WORD_XP", "").lower()
-        if forced in ("scalar", "int"):
-            return "scalar"
         if forced in ("np", "numpy", "host"):
             return np
-        use_jax = forced in ("jax", "jnp", "device")
-        if not use_jax:
-            try:
-                from mythril_tpu.ops.device_health import backend_name
-
-                use_jax = batch >= 16 and backend_name() == "tpu"
-            except Exception:  # noqa: BLE001 — policy only
-                use_jax = False
-        if not use_jax:
+        if forced not in ("jax", "jnp", "device"):
             return "scalar"
         try:
             import jax.numpy as jnp

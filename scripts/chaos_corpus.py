@@ -164,8 +164,7 @@ def _kr_configure():
     args.device_force_dispatch = True
     args.word_probing = False
     args.async_dispatch = False  # dispatches stay on the kill path
-    if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
-        os.environ.setdefault("MYTHRIL_TPU_PALLAS", "off")
+    os.environ.setdefault("MYTHRIL_TPU_PALLAS", "off")  # CPU: gather path
 
 
 def _kr_child(checkpoint_dir, resume) -> int:
@@ -1611,6 +1610,10 @@ def main() -> int:
     parser.add_argument("--kr-resume", action="store_true",
                         help=argparse.SUPPRESS)
     args_ns = parser.parse_args()
+    # a soak runs several analyzer processes at once (serve + workers,
+    # victim + resume) and a chip belongs to one process: every process
+    # of a chaos run solves on the CPU, children inherit the pin
+    os.environ["JAX_PLATFORMS"] = "cpu"
     if args_ns.kr_child:
         return _kr_child(args_ns.kr_dir, args_ns.kr_resume)
     if args_ns.kill_resume:
@@ -1639,11 +1642,10 @@ def main() -> int:
     args.device_min_lanes = 2
     args.device_force_dispatch = True
     args.word_probing = False
-    if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
-        # auto mode refuses gather dispatch on a CPU-only host (the
-        # cpu_auto_skips gate); pin the gather path explicitly so the
-        # injected dispatch faults have a dispatch to hit
-        os.environ.setdefault("MYTHRIL_TPU_PALLAS", "off")
+    # auto mode refuses gather dispatch on the CPU (the cpu_auto_skips
+    # gate); pin the gather path explicitly so the injected dispatch
+    # faults have a dispatch to hit
+    os.environ.setdefault("MYTHRIL_TPU_PALLAS", "off")
 
     print("reference (fault-free) pass ...", file=sys.stderr)
     reference, _ = _analyze_corpus()

@@ -231,6 +231,21 @@ def test_plane_cadence_and_demotion_nudge(tmp_path, monkeypatch):
     assert resilience_stats.checkpoint_s >= 0.0
 
 
+def test_process_demotion_rides_the_journal(tmp_path):
+    """A demotion decided in-process (from a dispatch's own error)
+    lands in the journal with its reason, so a resumed run starts
+    demoted instead of re-dispatching to a lost device."""
+    from mythril_tpu.ops import device_health
+
+    device_health.mark_unhealthy("gather dispatch failed: UNAVAILABLE")
+    plane = cp.get_checkpoint_plane()
+    plane.configure(str(tmp_path))
+    plane.transaction_boundary(_FakeLaser(), 0xABC, 0)
+    assert cp.load_journal(str(tmp_path))["device_demoted"] == (
+        "gather dispatch failed: UNAVAILABLE"
+    )
+
+
 def test_resume_rejects_mismatched_target(tmp_path):
     plane = cp.get_checkpoint_plane()
     plane.configure(str(tmp_path))

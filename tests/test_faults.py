@@ -85,9 +85,8 @@ def chaos_env(monkeypatch):
     yield
     faults.reset_for_tests()
     watchdog.reset_for_tests()
-    # an injected probe flap pins the cached health verdict to dead —
-    # re-probe (cheap: this process is JAX_PLATFORMS=cpu) for the rest
-    # of the suite
+    # an injected probe flap or device loss demotes the process —
+    # undo it for the rest of the suite
     from mythril_tpu.ops import device_health
 
     device_health.reset_for_tests()
@@ -165,7 +164,7 @@ def test_dispatch_hang_trips_watchdog_and_demotes(monkeypatch):
     assert row["watchdog_trips"] >= 1
     assert row["demotions"] >= 1
     assert row["fused"] is True  # context demoted to the CDCL tail
-    assert row["dispatches"] == 0  # nothing engaged past the wedge
+    assert row["dispatches"] == 0  # nothing engaged past the hang
     # deadline budget: 3 attempts x 0.4s + backoff, then pure CDCL —
     # nowhere near the 30s an unsupervised hang would cost per dispatch
     assert wall < 20, wall
@@ -195,15 +194,16 @@ def test_dispatch_error_exhaustion_demotes(monkeypatch):
 
 def test_probe_flap_mid_run_degrades_to_unhealthy_skips():
     base_found, _ = _baseline()
-    # skip=1: the first dispatch's health check passes, the flap lands
-    # mid-analysis — exactly the wedge-after-healthy-verdict scenario
+    # skip=1: the first dispatch's device check passes, the flap lands
+    # mid-analysis — a device lost after a healthy start
     faults.get_fault_plane().arm("probe_flap", times=1, skip=1)
     found, row = _analyze()
     assert found == base_found
     assert row["unhealthy_skips"] >= 1
-    from mythril_tpu.ops.device_health import device_ok
+    from mythril_tpu.ops.device_health import demotion, device_ok
 
-    assert device_ok() is False  # verdict stays dead until re-probed
+    assert device_ok() is False  # demotion lasts for the process
+    assert demotion() == "injected probe flap"  # the warning names it
 
 
 def test_cdcl_raise_is_retried_and_findings_survive():
@@ -334,26 +334,73 @@ def test_watchdog_deadline_follows_the_latency_ewma(monkeypatch):
     assert dog.deadline_for("k") == 0.3  # operator cap always wins
 
 
-def test_ladder_demotes_process_when_reprobe_fails(monkeypatch):
-    """Rung 4: retries exhausted AND the subprocess re-probe says the
-    device is gone -> the whole process demotes (device_ok flips)."""
+@pytest.mark.parametrize("error, lost", [
+    ("UNAVAILABLE: TPU device lost", True),
+    ("DATA_LOSS: device memory corrupted", True),
+    ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem", False),
+    ("INTERNAL: Mosaic failed to compile TPU kernel", False),
+])
+def test_ladder_demotes_process_only_on_device_loss(monkeypatch, error,
+                                                     lost):
+    """Last rung: retries exhausted -> the context always demotes; the
+    whole process demotes only when the failing dispatch's OWN error
+    says the device is gone (decided in-process, no child probe)."""
+    from mythril_tpu.ops import device_health
+
+    monkeypatch.setenv("MYTHRIL_TPU_DISPATCH_BACKOFF_S", "0.01")
+    resilience_stats.reset()
+
+    def fail():
+        raise RuntimeError(error)
+
+    dog = watchdog.DispatchWatchdog()
+    with pytest.raises(watchdog.DispatchAbandoned) as exc_info:
+        dog.supervised("k", fail)
+    assert exc_info.value.process_demoted is lost
+    assert device_health.device_ok() is (not lost)
+    if lost:
+        assert error in device_health.demotion()
+    assert resilience_stats.demotions == 1
+    assert resilience_stats.dispatch_retries == 2
+
+
+def test_compile_time_extends_the_deadline_and_skips_the_ewma(
+    monkeypatch
+):
+    """Time a supervised worker spends inside a jax compile is set-up,
+    not a hung device: it extends the deadline and stays out of the
+    latency model.  The same wait outside a compile trips."""
+    import jax.monitoring
+
+    monkeypatch.setenv("MYTHRIL_TPU_DISPATCH_TIMEOUT", "0.3")
+    event = "/jax/core/compile/backend_compile_duration"
+
+    def compile_then_run():
+        jax.monitoring.record_scalar(event, time.time())
+        time.sleep(0.6)
+        jax.monitoring.record_event_duration_secs(event, 0.6)
+        return "ran"
+
+    dog = watchdog.DispatchWatchdog()
+    assert dog.run("k", compile_then_run) == "ran"
+    assert dog._ewma["k"] < 0.3
+    with pytest.raises(watchdog.WatchdogTimeout):
+        dog.run("k2", lambda: time.sleep(0.6))
+
+
+def test_ladder_hang_demotes_the_context_not_the_process(monkeypatch):
+    """A deadline miss on every attempt is local to its dispatch: the
+    context demotes, the process stays on the device."""
     from mythril_tpu.ops import device_health
 
     monkeypatch.setenv("MYTHRIL_TPU_DISPATCH_TIMEOUT", "0.2")
     monkeypatch.setenv("MYTHRIL_TPU_DISPATCH_BACKOFF_S", "0.01")
-    # pretend we are not CPU-pinned so the re-probe rung runs, and make
-    # the re-probe itself fail
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-    monkeypatch.setattr(
-        device_health, "subprocess_probe_ok", lambda timeout_s=None: False
-    )
-    device_health._verdict = True  # pre-flap healthy verdict
     resilience_stats.reset()
     dog = watchdog.DispatchWatchdog()
     with pytest.raises(watchdog.DispatchAbandoned) as exc_info:
         dog.supervised("k", lambda: time.sleep(5))
-    assert exc_info.value.process_demoted is True
-    assert device_health.device_ok() is False
+    assert exc_info.value.process_demoted is False
+    assert device_health.device_ok() is True
     assert resilience_stats.demotions == 1
     assert resilience_stats.watchdog_trips == 3
 
@@ -361,7 +408,6 @@ def test_ladder_demotes_process_when_reprobe_fails(monkeypatch):
 def test_cancellation_checkpoint_stops_abandoned_workers(monkeypatch):
     monkeypatch.setenv("MYTHRIL_TPU_DISPATCH_TIMEOUT", "0.2")
     monkeypatch.setenv("MYTHRIL_TPU_DISPATCH_RETRIES", "0")
-    monkeypatch.setenv("MYTHRIL_TPU_REPROBE", "0")
     progressed = []
     resumed = threading.Event()
 

@@ -6,9 +6,14 @@ dp x cp sharded mesh dispatch on 8 virtual devices, and per-lane
 verdict parity against the host CDCL."""
 
 import importlib
+import os
+import shutil
+import subprocess
 import sys
 
 import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _graft():
@@ -37,3 +42,20 @@ def test_dryrun_multichip_on_virtual_mesh(capsys):
     tail = capsys.readouterr().out
     assert "dryrun_multichip OK" in tail
     assert "EVM-derived lanes" in tail
+
+
+@pytest.mark.parametrize("where", ["cpu_only", "script_alone"])
+def test_chip_smoke_gives_no_result_without_a_chip(tmp_path, where):
+    """chip_smoke.py exits nonzero and never prints the ok line when JAX
+    finds no TPU, or when the repository is not around the script."""
+    script = os.path.join(REPO, "chip_smoke.py")
+    cwd = REPO
+    if where == "script_alone":
+        script = shutil.copy(script, tmp_path)
+        cwd = str(tmp_path)
+    proc = subprocess.run(
+        [sys.executable, script], capture_output=True, text=True,
+        timeout=120, cwd=cwd, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout

@@ -510,18 +510,18 @@ def test_no_new_compiles_after_warmup_same_bucket(monkeypatch):
     assert len(builds) == warm, "second same-shape dispatch recompiled"
 
 
-def test_bench_pins_persistent_compile_cache(monkeypatch):
-    import bench
-
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    cache_dir = bench._enable_compile_cache()
-    assert cache_dir.endswith(".jax_cache")
+def test_compile_cache_dir_is_placed_from_outside(monkeypatch):
+    """One place decides the persistent compile cache: the operator's
+    JAX_COMPILATION_CACHE_DIR when set, else <repo>/.jax_cache."""
     import os
 
-    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == cache_dir
-    # an operator-provided dir wins
+    from mythril_tpu.ops import compile_cache_dir
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache_dir() == os.path.join(repo, ".jax_cache")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/opcache")
-    assert bench._enable_compile_cache() == "/tmp/opcache"
+    assert compile_cache_dir() == "/tmp/opcache"
 
 
 if __name__ == "__main__":

@@ -8,6 +8,7 @@ trusted z3 (SURVEY.md §4).
 """
 
 import random
+import time
 
 import numpy as np
 import pytest
@@ -322,6 +323,50 @@ def test_futile_dispatch_fuse(monkeypatch):
     BS.batch_check_states([[y == 1], [y == 2]])
     assert BS.dispatch_stats.dispatches == fused_count + 1
     assert backend.fused_generation != ctx2.generation
+
+
+@pytest.mark.parametrize("compiling", [True, False])
+def test_slow_fuse_nets_out_compile_time(monkeypatch, compiling):
+    """One zero-decision dispatch slower than SLOW_DISPATCH_FUSE_S
+    fuses the device off for the context — unless the time went to a
+    jax compile: a cold kernel compile is set-up, and counting it fused
+    a v5e off after its first dispatch, so a warm-cache rerun took a
+    different path."""
+    import jax.monitoring
+
+    monkeypatch.setenv("MYTHRIL_TPU_PALLAS", "off")
+    from mythril_tpu.ops import batched_sat as BS
+    from mythril_tpu.support.support_args import args
+
+    monkeypatch.setattr(args, "device_min_lanes", 2)
+    monkeypatch.setattr(args, "word_probing", False)
+    monkeypatch.setattr(args, "device_force_dispatch", True)
+    monkeypatch.setattr(args, "device_coalesce", False)
+    monkeypatch.setattr(BS, "SLOW_DISPATCH_FUSE_S", 0.2)
+    backend = BS.get_backend()
+    event = "/jax/core/compile/backend_compile_duration"
+
+    def slow_check(self, ctx, sets, search=True):
+        if compiling:
+            jax.monitoring.record_scalar(event, time.time())
+        time.sleep(0.4)
+        if compiling:
+            jax.monitoring.record_event_duration_secs(event, 0.4)
+        self.device_engaged = True
+        self.last_assignments = np.zeros(
+            (len(sets), ctx.solver.num_vars + 1), np.int8
+        )
+        return [None] * len(sets)
+
+    monkeypatch.setattr(
+        BS.BatchedSatBackend, "check_assumption_sets", slow_check
+    )
+    reset_blast_context()
+    ctx = get_blast_context()
+    x = symbol_factory.BitVecSym("slow_fuse_x", 16)
+    BS.batch_check_states([[x == 1], [x == 2]])
+    assert (backend.fused_generation == ctx.generation) is not compiling
+    assert BS.dispatch_stats.fused is not compiling
 
 
 def test_fuse_retry_rearms_on_decision(monkeypatch):

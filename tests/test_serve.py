@@ -582,9 +582,16 @@ def test_repeated_poison_trips_breaker_then_recovers(server):
 
 def test_device_demotion_degrades_but_serves(server):
     from mythril_tpu.ops import device_health
+    from mythril_tpu.resilience import watchdog
 
     try:
-        device_health.mark_unhealthy("test demotion")
+        # the ladder's last rung, fed a dispatch error that says the
+        # device is gone, demotes the whole process in-process
+        with pytest.raises(watchdog.DispatchAbandoned) as exc_info:
+            watchdog.DispatchWatchdog().give_up(
+                "serve-test", RuntimeError("UNAVAILABLE: device lost")
+            )
+        assert exc_info.value.process_demoted is True
         status, raw = _get(server, "/readyz")
         ready = json.loads(raw)
         assert status == 200          # degraded is NOT unready

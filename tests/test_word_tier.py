@@ -336,6 +336,18 @@ def test_scalar_and_batched_executors_agree(monkeypatch):
     assert checked == 25
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_auto_executor_is_scalar_on_every_backend(monkeypatch, backend):
+    """The eager jax executor is opt-in only: on a v5e it was the auto
+    pick for 16+ lanes and ran one dispatch per op, so a wide-frontier
+    analysis hit its execution timeout (PR 21)."""
+    from mythril_tpu.ops import device_health
+
+    monkeypatch.delenv("MYTHRIL_TPU_WORD_XP", raising=False)
+    monkeypatch.setattr(device_health, "backend_name", lambda: backend)
+    assert get_word_tier()._executor(64) == "scalar"
+
+
 def test_jax_device_executor_agrees(monkeypatch):
     """One batch through the jax.numpy limb-plane executor (the device
     path, CPU backend here) must match the scalar host walk.  Kept
