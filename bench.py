@@ -468,31 +468,31 @@ def _analyze_one(name, code, tx_count, execution_timeout, max_depth):
     stats.reset()
     contract = EVMContract(code=code, name=name)
     time_handler.start_execution(execution_timeout)
-    # span-derived per-phase breakdown: snapshot the tracer's per-name
-    # totals so this contract's cone/upload/sweep/tail seconds come
-    # from the SAME spans --trace-out would show (zeros when the
-    # tracer is off)
-    span_base = obs_spans.totals_snapshot()
     t0 = time.time()
-    sym = SymExecWrapper(
-        contract,
-        address=0x901D12EBE1B195E5AA8748E62BD7734AE19B51F,
-        strategy="bfs",
-        max_depth=max_depth,
-        execution_timeout=execution_timeout,
-        create_timeout=10,
-        transaction_count=tx_count,
-        # what every production entry point passes (mythril_analyzer,
-        # serve/engine, parallel/fleet): all detection modules are
-        # CALLBACK, so recording the full statespace is pure overhead —
-        # and requires_statespace pins the lockstep tier off, so leaving
-        # the default here would bench a pipeline nothing else runs
-        compulsory_statespace=False,
-    )
-    issues = fire_lasers(sym)
-    # an unharvested prefetch belongs to THIS contract's row: drop it
-    # before the telemetry snapshot below
-    get_async_dispatcher().drop()
+    # this contract's analysis record: self seconds by span, the same
+    # spans --trace-out would show
+    with obs_spans.analysis("harness.analyze"):
+        sym = SymExecWrapper(
+            contract,
+            address=0x901D12EBE1B195E5AA8748E62BD7734AE19B51F,
+            strategy="bfs",
+            max_depth=max_depth,
+            execution_timeout=execution_timeout,
+            create_timeout=10,
+            transaction_count=tx_count,
+            # what every production entry point passes
+            # (mythril_analyzer, serve/engine, parallel/fleet): all
+            # detection modules are CALLBACK, so recording the full
+            # statespace is pure overhead — and requires_statespace
+            # pins the lockstep tier off, so leaving the default here
+            # would bench a pipeline nothing else runs
+            compulsory_statespace=False,
+        )
+        issues = fire_lasers(sym)
+        # an unharvested prefetch belongs to THIS contract's row: drop
+        # it before the telemetry snapshot below
+        get_async_dispatcher().drop()
+    record = obs_spans.analysis_profiles()[-1]
     found = {i.swc_id for i in issues}
     wall = time.time() - t0
     dd = dispatch_stats.as_dict()
@@ -521,12 +521,13 @@ def _analyze_one(name, code, tx_count, execution_timeout, max_depth):
         "dispatches_per_analysis": dd.get("device_dispatch_calls", 0),
         **{k: round(v, 3) if isinstance(v, float) else v
            for k, v in async_stats.as_dict().items()},
-        # per-phase wall breakdown derived from the observability
-        # spans (cone extraction / H2D upload / device sweep rounds /
-        # CDCL tail) — the same data --trace-out exports, not a
-        # parallel set of ad-hoc monotonic pairs
-        **{f"span_{k}": v
-           for k, v in obs_spans.phase_totals(base=span_base).items()},
+        # where the wall went, from the observability spans: seconds
+        # inside each span and none of its children, and the host's
+        # waits on the device by site
+        "span_self_s": {k: round(v, 4)
+                        for k, v in record["self_s"].items()},
+        "host_wait_s": {k: round(v, 4)
+                        for k, v in record["wait_s"].items()},
         "device_status": DEVICE_STATUS,
     }
     return found, row
@@ -1533,26 +1534,26 @@ def build_headline_line(summary, mesh_scale, microbench) -> str:
     return line
 
 
-def _enable_tracing_and_calibrate() -> float:
-    """Enable the observability span tracer in totals-only mode (per-
-    name durations, no event buffer) so every row's phase breakdown is
-    span-derived, and measure the per-span bookkeeping cost.  The
-    headline ``trace_overhead_s`` is that unit cost times the spans
-    actually recorded over the run — the number the <2%% disabled-path
-    budget is judged on.  Honors the ``MYTHRIL_TPU_TRACE=0`` kill
-    switch (returns 0.0: spans are no-ops, breakdowns read zero)."""
+def _profile_spans() -> int:
     from mythril_tpu.observability import spans as obs_spans
 
-    tracer = obs_spans.get_tracer()
-    if not tracer.enable(record_events=False):
-        return 0.0
+    return obs_spans.profile_counts()["spans"]
+
+
+def _calibrate_span_cost() -> float:
+    """The per-span cost of the always-on self-time profile (tracing
+    off), measured inside a throwaway analysis record.  The headline
+    ``trace_overhead_s`` is that unit cost times the spans the run's
+    analysis records counted."""
+    from mythril_tpu.observability import spans as obs_spans
+
     n = 20_000
-    began = time.perf_counter()
-    for _ in range(n):
-        with obs_spans.span("bench.calibrate"):
-            pass
-    per_span = (time.perf_counter() - began) / n
-    tracer.reset()  # calibration spans must not pollute row breakdowns
+    with obs_spans.analysis("harness.calibrate"):
+        began = time.perf_counter()
+        for _ in range(n):
+            with obs_spans.span("harness.calibrate"):
+                pass
+        per_span = (time.perf_counter() - began) / n
     return per_span
 
 
@@ -1561,7 +1562,8 @@ def main() -> None:
 
     logging.basicConfig(level=logging.CRITICAL)
     logging.getLogger("mythril_tpu").setLevel(logging.CRITICAL)
-    per_span_s = _enable_tracing_and_calibrate()
+    per_span_s = _calibrate_span_cost()
+    spans_before = _profile_spans()
 
     argv = sys.argv[1:]
     all_modes = "--all-modes" in argv
@@ -1911,13 +1913,10 @@ def main() -> None:
             summary["merges_per_1k_states"] = round(
                 t45_merges / t45_steps * 1000, 2
             )
-    # tracing self-cost estimate: measured per-span bookkeeping cost x
-    # events actually recorded across every pass of this process (the
-    # headline field bench_compare gates; 0.0 with MYTHRIL_TPU_TRACE=0)
-    from mythril_tpu.observability import spans as obs_spans
-
-    tracer = obs_spans.get_tracer()
-    summary["trace_events"] = tracer.span_count + tracer.instant_count
+    # self-time profile cost estimate: measured per-span cost x spans
+    # the analysis records counted across every pass of this process
+    # (the headline field bench_compare gates)
+    summary["trace_events"] = _profile_spans() - spans_before
     summary["trace_overhead_s"] = round(
         per_span_s * summary["trace_events"], 4
     )

@@ -88,12 +88,11 @@ def myth_analyze(code: str, tx_count: int, *flags: str) -> dict:
     """One ``myth analyze -o jsonv2`` run in this process; returns the
     findings as sorted (swc, address) pairs plus the run's counters."""
     from mythril_tpu.interfaces.cli import main
-    from mythril_tpu.observability import spans
+    from mythril_tpu.observability import analysis_profiles
     from mythril_tpu.ops.batched_sat import dispatch_stats
     from mythril_tpu.smt.solver import SolverStatistics
 
     _fresh_state()
-    span_base = spans.totals_snapshot()
     argv = ["myth", "analyze", "-v", "3", "-c", code, "--bin-runtime",
             "-t", str(tx_count), "--no-onchain-data",
             "--execution-timeout", str(EXECUTION_TIMEOUT_S),
@@ -126,16 +125,16 @@ def myth_analyze(code: str, tx_count: int, *flags: str) -> dict:
         "device_dispatch_calls", "lanes", "fused", *SILENT_COUNTERS,
     )}
     # where the wall went: the solver's own split, device dispatch
-    # time, and the heaviest spans (inclusive seconds, totals-only
-    # tracer — the same spans --trace-out would record)
+    # time, and the spans with the most self time in the analysis's
+    # record (the same spans --trace-out would record), with its waits
+    # on the device and its compile seconds
     split = dict(SolverStatistics().split(), device_s=stats["device_s"])
     split["other_s"] = wall - sum(split.values())
-    totals = spans.totals_snapshot()
-    heavy = sorted(((name, sec - span_base.get(name, 0.0))
-                    for name, sec in totals.items()),
-                   key=lambda item: -item[1])[:6]
+    record = analysis_profiles()[-1]
+    heavy = sorted(record["self_s"].items(), key=lambda item: -item[1])[:6]
     return {"wall_s": wall, "findings": findings, "counters": counters,
-            "split_s": split, "spans_s": dict(heavy)}
+            "split_s": split, "self_s": dict(heavy),
+            "wait_s": record["wait_s"], "compile_s": record["compile_s"]}
 
 
 def _expect(run: dict, swc: str, *, device: bool) -> None:
@@ -251,9 +250,6 @@ def main() -> int:
     try:
         device = _device(opts.chips)
         configure_jax()
-        from mythril_tpu.observability import spans
-
-        spans.get_tracer().enable(record_events=False)
         print(f"compile cache: {os.environ.get('JAX_COMPILATION_CACHE_DIR') or 'default'}"
               f" ({len(_cache_entries())} entries)", flush=True)
         if opts.chips == 1:

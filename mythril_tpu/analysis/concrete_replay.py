@@ -31,6 +31,8 @@ from typing import List, Optional
 
 import numpy as np
 
+from mythril_tpu.observability import spans as obs
+
 log = logging.getLogger(__name__)
 
 MAX_REPLAY_STEPS = 65536
@@ -69,25 +71,30 @@ def replay_issue(issue, runtime_code: bytes) -> Optional[str]:
         caller = _hex_int(step.get("origin"))
         value = _hex_int(step.get("value"))
 
-        state = lockstep.init_state(
-            1,
-            np.asarray([list(calldata)], np.uint8).reshape(1, len(calldata)),
-            np.asarray([len(calldata)], np.int32),
-            callvalue=_word_limbs(value)[None, :],
-            caller=_word_limbs(caller)[None, :],
-            storage_keys=skeys,
-            storage_vals=svals,
-        )
-        try:
-            final, _ = lockstep.run_batch(
-                runtime_code, state, MAX_REPLAY_STEPS
+        # a concrete run on the device: its uploads and its result can
+        # queue behind a kernel already running there (a prefetch)
+        with obs.wait("replay.upload"):
+            state = lockstep.init_state(
+                1,
+                np.asarray([list(calldata)], np.uint8).reshape(
+                    1, len(calldata)),
+                np.asarray([len(calldata)], np.int32),
+                callvalue=_word_limbs(value)[None, :],
+                caller=_word_limbs(caller)[None, :],
+                storage_keys=skeys,
+                storage_vals=svals,
             )
-        except Exception as e:  # noqa: BLE001 — validation must not fail analysis
-            log.debug("lockstep replay unavailable: %s", e)
-            return None
+            try:
+                final, _ = lockstep.run_batch(
+                    runtime_code, state, MAX_REPLAY_STEPS
+                )
+            except Exception as e:  # noqa: BLE001 — validation must not fail analysis
+                log.debug("lockstep replay unavailable: %s", e)
+                return None
 
-        halt = int(np.asarray(final.halt)[0])
-        pc = int(np.asarray(final.pc)[0])
+        with obs.wait("replay.fetch"):
+            halt = int(np.asarray(final.halt)[0])
+            pc = int(np.asarray(final.pc)[0])
         if halt == lockstep.RUNNING:
             return "unsupported"  # step cap exhausted mid-transaction
         if halt == lockstep.NEEDS_HOST:

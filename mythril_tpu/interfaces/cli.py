@@ -877,27 +877,19 @@ def _build_analyzer(
 
 
 def _fire_and_print(analyzer: MythrilAnalyzer, args: argparse.Namespace) -> None:
-    from mythril_tpu.observability import finalize_outputs, span
-
-    with span("cli.analyze", cat="cli"):
-        report = analyzer.fire_lasers(
-            modules=[m.strip() for m in args.modules.strip().split(",")]
-            if args.modules
-            else None,
-            transaction_count=args.transaction_count,
-        )
-        renderers = {
-            "json": report.as_json,
-            "jsonv2": report.as_swc_standard_format,
-            "text": report.as_text,
-            "markdown": report.as_markdown,
-        }
-        rendered = renderers[getattr(args, "outform", "text")]()
-    # --trace-out / --metrics-out artifacts land BEFORE the report hits
-    # stdout: a consumer that closes the pipe early (head, a crashed
-    # reader) must not cost the run its timeline
-    finalize_outputs()
-    print(rendered)
+    report = analyzer.fire_lasers(
+        modules=[m.strip() for m in args.modules.strip().split(",")]
+        if args.modules
+        else None,
+        transaction_count=args.transaction_count,
+    )
+    renderers = {
+        "json": report.as_json,
+        "jsonv2": report.as_swc_standard_format,
+        "text": report.as_text,
+        "markdown": report.as_markdown,
+    }
+    print(renderers[getattr(args, "outform", "text")]())
 
 
 def execute_truffle(args: argparse.Namespace) -> None:
@@ -1031,6 +1023,24 @@ def execute_command(
 
 
 def parse_args_and_execute(parser: argparse.ArgumentParser, args: argparse.Namespace) -> None:
+    if args.command not in ANALYZE_LIST and args.command != "truffle":
+        _execute(parser, args)
+        return
+    from mythril_tpu.observability import analysis, finalize_outputs
+
+    # the analysis record opens before the contract is loaded and closes
+    # once the report is printed, so its wall is the command's; the
+    # --trace-out / --metrics-out artifacts, which carry the record,
+    # are written after it closes, also when printing or the analysis
+    # failed
+    try:
+        with analysis("cli.analyze"):
+            _execute(parser, args)
+    finally:
+        finalize_outputs()
+
+
+def _execute(parser: argparse.ArgumentParser, args: argparse.Namespace) -> None:
     if args.epic:
         import shlex
         import subprocess

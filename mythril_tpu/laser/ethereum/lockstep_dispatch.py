@@ -42,6 +42,8 @@ from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
+from mythril_tpu.observability import spans as obs
+
 log = logging.getLogger(__name__)
 
 MAX_SELECTORS = 64
@@ -160,12 +162,17 @@ def _validate_on_lockstep(code_hex: str, plan: DispatcherPlan):
         calldata = np.zeros((batch, 36), np.uint8)
         for lane, selector in enumerate(selectors):
             calldata[lane, :4] = list(selector.to_bytes(4, "big"))
-        state = lockstep.init_state(
-            batch, calldata, np.full(batch, 36, np.int32)
-        )
-        _final, visited, _steps = lockstep.run_batch(
-            code, state, max_steps=VALIDATE_STEPS, record_visited=True
-        )
+        # a concrete run on the device: its uploads and its result
+        # can queue behind a kernel already running there (a prefetch)
+        with obs.wait("lockstep.upload"):
+            state = lockstep.init_state(
+                batch, calldata, np.full(batch, 36, np.int32)
+            )
+            _final, visited, _steps = lockstep.run_batch(
+                code, state, max_steps=VALIDATE_STEPS, record_visited=True
+            )
+        with obs.wait("lockstep.fetch"):
+            visited = np.asarray(visited)
         return all(
             lockstep.pc_visited(visited, lane, plan.branches[sel][0])
             for lane, sel in enumerate(selectors)

@@ -7,7 +7,10 @@ Four pillars, all zero-dependency and kill-switchable
 the ledger):
 
 - :mod:`.spans` — the tracer: context-manager/decorator spans with
-  thread-local nesting across the whole pipeline, instant events
+  thread-local nesting across the whole pipeline, each adding its self
+  time to the open analysis's record (:func:`analysis_profiles`: self
+  seconds by span, host waits on the device, compile and prefetch
+  seconds, always on), instant events
   (watchdog trips, faults, demotions, checkpoint writes), Perfetto
   counter tracks (live lanes, frontier queue depth, pool rows), and
   the cross-process trace identity minted at the CLI/serve edge;
@@ -37,16 +40,18 @@ from mythril_tpu.observability.flight import (  # noqa: F401
 from mythril_tpu.observability.ledger import get_ledger  # noqa: F401
 from mythril_tpu.observability.metrics import get_registry  # noqa: F401
 from mythril_tpu.observability.spans import (  # noqa: F401
+    analysis,
+    analysis_profiles,
     counter,
     get_trace_id,
     get_tracer,
     instant,
     new_trace_id,
-    phase_totals,
     set_trace_id,
     span,
     totals_snapshot,
     traced,
+    wait,
 )
 
 
@@ -67,7 +72,7 @@ def configure_from_cli(trace_out, metrics_out,
     # meta all carry it so a multi-process run stays one trace
     set_trace_id(new_trace_id())
     if trace_out:
-        get_tracer().enable(record_events=True)
+        get_tracer().enable()
     if trace_out or metrics_out or lane_ledger_out:
         install_excepthook()
 

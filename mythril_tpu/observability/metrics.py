@@ -16,7 +16,8 @@ bags — ``resilience/telemetry.py``, the ``DispatchStats`` fields in
   time* by registered collectors that mirror them as
   ``mythril_tpu_dispatch_*`` / ``mythril_tpu_async_*`` values;
 - the tracer/flight recorder report their own meta-counters
-  (``mythril_tpu_trace_*``).
+  (``mythril_tpu_trace_*``), and the analysis records their self
+  seconds by span (``mythril_tpu_span_self_seconds_total{span=...}``).
 
 Render-time dedupe guarantees each metric name appears exactly once in
 a dump even if a collector misbehaves.  Everything is stdlib-only and
@@ -293,6 +294,23 @@ def _trace_collector():
            get_flight_recorder().dumps_written)
 
 
+def _span_profile_collector():
+    """Self seconds by span name over every closed analysis record
+    (observability/spans.py), and how many records closed."""
+    from mythril_tpu.observability.spans import (
+        profile_counts, self_seconds_totals,
+    )
+
+    yield ("counter", "mythril_tpu_analysis_records_total",
+           "analysis records closed", profile_counts()["analyses"])
+    for name, seconds in sorted(self_seconds_totals().items()):
+        yield ("counter",
+               f'mythril_tpu_span_self_seconds_total'
+               f'{{span="{escape_label_value(name)}"}}',
+               "seconds inside a span and none of its children, "
+               "summed over analyses", seconds)
+
+
 _registry: Optional[MetricsRegistry] = None
 _registry_lock = threading.Lock()
 
@@ -306,6 +324,7 @@ def get_registry() -> MetricsRegistry:
                 registry.register_collector(_dispatch_stats_collector)
                 registry.register_collector(_async_stats_collector)
                 registry.register_collector(_trace_collector)
+                registry.register_collector(_span_profile_collector)
                 registry.register_collector(_ledger_collector)
                 registry.register_collector(_autopilot_collector)
                 _registry = registry

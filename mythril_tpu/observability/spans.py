@@ -1,69 +1,63 @@
 """Hierarchical spans: the zero-dependency tracer of the observability
-plane.
+plane, and the per-analysis self-time profile built on it.
 
-One process-wide :class:`Tracer` records *spans* (named, timed regions
-with thread-local nesting) and *instant events* (watchdog trips, fault
-injections, demotions, checkpoint writes) and exports them as
-Chrome/Perfetto ``trace_event`` JSON (``myth analyze --trace-out FILE``,
-open at https://ui.perfetto.dev).  The span taxonomy covers the whole
-pipeline — CLI → analyzer → svm transaction rounds → frontier pruning →
-device dispatch → ladder rounds → H2D uploads → the CDCL tail — so a
-slow ``t3_wall_s`` is attributable to a *layer*, not just a counter
-delta (docs/observability.md).
+Every :func:`span` times itself, whether or not tracing is on, and
+keeps a thread-local sum of the time its children took.  On exit it
+adds its *self* time (duration minus children) to the record of the
+analysis open on that thread.  The root span of an analysis
+(:func:`analysis`, ``cli.analyze`` for ``myth analyze``) opens the
+record and closes it into a bounded ring that
+:func:`analysis_profiles` reads: wall, self seconds by span name, host
+waits on the device by site (:func:`wait`), compile seconds, the
+async prefetch's seconds in flight, program counts, and the span count.
+The self seconds of one record add up to its wall: each span's time is
+counted once, in the innermost span that covers it.
 
-Design constraints, in order:
+One process-wide :class:`Tracer` additionally records every span as a
+completed ``ph: "X"`` event, plus *instant events* (watchdog trips,
+fault injections, demotions, checkpoint writes) and counter tracks,
+when tracing is on (``myth analyze --trace-out FILE``,
+``MYTHRIL_TPU_TRACE=1``), and exports them as Chrome/Perfetto
+``trace_event`` JSON (open at https://ui.perfetto.dev).  The kill
+switch ``MYTHRIL_TPU_TRACE=0`` wins over every programmatic
+``enable()``; it turns off events, not the self-time profile.
 
-1. **Disabled paths are near-zero-cost.**  ``span()`` with no stats
-   sink returns a module-level no-op singleton after a single attribute
-   check — no allocation, no clock read.  ``instant()`` is one check
-   and a return.  The kill switch ``MYTHRIL_TPU_TRACE=0`` wins over
-   every programmatic ``enable()``.
-2. **Spans are the timing primitive.**  Call sites that must keep
-   feeding wall-clock counters even when tracing is off (the
-   ``SolverStatistics`` split, ``DispatchStats.device_s``) pass
-   ``sink=(obj, field)``: the span always times and accumulates into
-   the sink, and *additionally* lands on the timeline when tracing is
-   on — one clock pair, two consumers, so ``--trace-out`` and the bench
-   breakdown can never disagree.
-3. **Bounded memory.**  The event buffer is capped
-   (``MYTHRIL_TPU_TRACE_CAP``, default 200k events); overflow drops the
-   event (counted in ``dropped``) but still updates the per-name totals
-   that back :func:`phase_totals`.  The flight recorder
-   (observability/flight.py) keeps its own ring of the most recent
-   events independent of this cap.
+While a ``jax.profiler`` trace is recording, every span also opens a
+profiler ``TraceMe`` under its own name, so the program's spans sit on
+the profiler's host plane, on the same clock as the device's ops.  The
+``TraceMe`` class is looked up only once jax has been imported by
+someone else: this package imports only the stdlib.
 
-Thread model: events append under one lock; span *stacks* are
-thread-local, so nesting/parent attribution is correct per thread and
-Perfetto renders each thread's track from ts/dur containment.
+Threads: span stacks and the open record are thread-local.  A thread
+that does work another thread waits for (the supervised dispatch
+worker of resilience/watchdog.py) borrows that thread's open span and
+record (:func:`lend` / :func:`borrow`), so its spans nest under the
+waiting span and count in the same analysis.  Any other thread (the
+async prefetch worker) keeps its spans out of every record.
+
+Sinks: call sites that feed a wall-clock counter (the
+``SolverStatistics`` split, ``DispatchStats.device_s``) pass
+``sink=(obj, field)``; the span adds its duration there on exit — one
+clock pair, every consumer.
 """
 
 import json
 import os
+import sys
 import threading
 import time
 import uuid
-from typing import Dict, Optional
+from collections import deque
+from typing import Dict, List, Optional
 
 #: event-buffer cap (events beyond it are dropped, counted, and still
 #: totaled); override with MYTHRIL_TPU_TRACE_CAP
 TRACE_CAP = 200_000
+#: closed analysis records kept for :func:`analysis_profiles`; the
+#: oldest is dropped first
+PROFILE_CAP = 1024
 
-#: span-name prefixes -> bench phase buckets (cone / upload / sweep /
-#: tail).  Leaf names only: enclosing spans (dispatch.batch_check,
-#: svm.transaction) would double-count their children.
-PHASE_PREFIXES = (
-    ("cone.", "cone"),
-    ("solver.cone", "cone"),
-    ("upload.", "upload"),
-    ("dispatch.round", "sweep"),
-    ("pallas.round", "sweep"),
-    ("cdcl.solve", "tail"),
-    ("word.", "word"),
-    ("frontier.round", "frontier"),
-    ("svm.segment", "lockstep"),
-)
-PHASE_KEYS = ("cone", "upload", "sweep", "tail", "word", "frontier",
-              "lockstep")
+_perf_ns = time.perf_counter_ns
 
 
 def _kill_switched() -> bool:
@@ -80,87 +74,374 @@ def _env_cap() -> int:
         return TRACE_CAP
 
 
-class _NoopSpan:
-    """Shared no-op span: returned (never allocated) on every disabled
-    ``span()`` call without a sink."""
+# -- per-thread state ----------------------------------------------------
 
-    __slots__ = ()
-    elapsed_s = 0.0
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def __call__(self, func):  # decorator form stays a no-op wrapper
-        return func
+#: thread ident -> that thread's span stack (the list object itself,
+#: registered once per thread; the /debug/requests endpoint reads it)
+_all_stacks: Dict[int, list] = {}
 
 
-_NOOP = _NoopSpan()
+class _ThreadState:
+    """The open spans of one thread, the analysis record their self
+    time goes to, and, on a borrowing thread, the lender's open span and
+    the loan itself."""
+
+    __slots__ = ("stack", "record", "base", "loan")
+
+    def __init__(self):
+        self.stack: list = []
+        self.record: Optional["AnalysisRecord"] = None
+        self.base: Optional["Span"] = None
+        self.loan: Optional["Loan"] = None
 
 
-class _StatSpan:
-    """Sink-only span: times the region and accumulates into
-    ``sink=(obj, field)`` — the disabled-tracing replacement for the old
-    ad-hoc ``time.monotonic()`` pairs, same cost (one clock pair)."""
-
-    __slots__ = ("_sink", "_t0", "elapsed_s")
-
-    def __init__(self, sink):
-        self._sink = sink
-        self.elapsed_s = 0.0
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.elapsed_s = time.perf_counter() - self._t0
-        obj, field = self._sink
-        setattr(obj, field, getattr(obj, field) + self.elapsed_s)
-        return False
+class _Local(threading.local):
+    def __init__(self):
+        self.state = _ThreadState()
+        _all_stacks[threading.get_ident()] = self.state.stack
 
 
-class _Span:
-    """Recording span: one completed ``ph: "X"`` trace event."""
+_local = _Local()
 
-    __slots__ = ("_tracer", "name", "cat", "_sink", "_attrs", "_t0_ns",
-                 "elapsed_s")
 
-    def __init__(self, tracer, name, cat, sink, attrs):
-        self._tracer = tracer
+def _attribution(state: _ThreadState):
+    """(enclosing span, open record) of a thread; both None when a loan
+    the thread runs under was revoked."""
+    loan = state.loan
+    if loan is not None and not loan.live:
+        return None, None
+    stack = state.stack
+    return (stack[-1] if stack else state.base), state.record
+
+
+# -- profiler bridge -----------------------------------------------------
+
+#: the profiler's TraceMe class once jax is loaded, False where it
+#: cannot be had, None until jax has been imported
+_trace_me = None
+
+
+def _resolve_trace_me():
+    global _trace_me
+    if "jax" not in sys.modules:
+        return None
+    try:
+        from jax.profiler import TraceAnnotation
+    except Exception:  # noqa: BLE001 — no profiler: spans stay host-only
+        _trace_me = False
+    else:
+        _trace_me = TraceAnnotation
+    return _trace_me
+
+
+# -- the analysis record -------------------------------------------------
+
+
+class AnalysisRecord:
+    """Where one analysis's wall went (see the module docstring)."""
+
+    __slots__ = ("name", "trace_id", "wall_s", "self_ns", "wait_ns",
+                 "counts", "spans", "prefetch_inflight_s", "_prefetch_since")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.trace_id: Optional[str] = None
+        self.wall_s = 0.0
+        self.self_ns: Dict[str, int] = {}
+        self.wait_ns: Dict[str, int] = {}
+        self.counts: Dict[str, int] = {}
+        self.spans = 0
+        self.prefetch_inflight_s = 0.0
+        self._prefetch_since: Optional[float] = None
+
+    def add(self, name: str, self_ns: int) -> None:
+        table = self.self_ns
+        table[name] = table.get(name, 0) + self_ns
+        self.spans += 1
+
+    def prefetch_launched(self) -> None:
+        """An async prefetch this analysis launched is in flight from
+        now (the dispatcher runs one at a time)."""
+        with _record_lock:
+            self._prefetch_since = time.perf_counter()
+
+    def prefetch_done(self) -> None:
+        """Its kernel completed (or failed): the seconds since the
+        launch count in this record, also after the record closed."""
+        with _record_lock:
+            if self._prefetch_since is not None:
+                self.prefetch_inflight_s += (time.perf_counter()
+                                             - self._prefetch_since)
+                self._prefetch_since = None
+
+    def _prefetch_s(self) -> float:
+        if self._prefetch_since is None:
+            return self.prefetch_inflight_s
+        return (self.prefetch_inflight_s + time.perf_counter()
+                - self._prefetch_since)
+
+    def as_dict(self) -> dict:
+        self_s = {name: ns / 1e9 for name, ns in self.self_ns.items()}
+        return {
+            "name": self.name,
+            "trace_id": self.trace_id,
+            "wall_s": self.wall_s,
+            "self_s": self_s,
+            "wait_s": {site: ns / 1e9 for site, ns in self.wait_ns.items()},
+            "compile_s": self_s.get("device.compile", 0.0),
+            # a prefetch still in flight counts up to now
+            "prefetch_inflight_s": self._prefetch_s(),
+            "fuse_skips": self.counts.get("fuse_skips", 0),
+            "spans": self.spans,
+        }
+
+
+_record_lock = threading.Lock()
+
+
+class _Profiles:
+    """The ring of closed records plus per-name self seconds summed
+    over every record ever closed (the ``--metrics-out`` family)."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.records = deque(maxlen=PROFILE_CAP)
+        self.self_total_ns: Dict[str, int] = {}
+        self.closed = 0
+        self.spans = 0
+
+    def close(self, record: AnalysisRecord) -> None:
+        with self.lock:
+            self.records.append(record)
+            self.closed += 1
+            self.spans += record.spans
+            totals = self.self_total_ns
+            for name, ns in record.self_ns.items():
+                totals[name] = totals.get(name, 0) + ns
+
+
+_profiles = _Profiles()
+
+
+# -- spans ---------------------------------------------------------------
+
+
+class Span:
+    """A named, timed region: times itself, nests per thread, adds its
+    self time to the open analysis record and its duration to ``sink``
+    (``(obj, field)``: ``field += elapsed`` on exit, a wall-clock
+    counter fed by the same clock pair), and lands on the timeline when
+    tracing is on.  ``span(name, sink=None, cat="pipeline", **attrs)``
+    is this class."""
+
+    __slots__ = ("name", "cat", "_sink", "_attrs", "_state", "_t0",
+                 "_child", "_tm", "elapsed_s")
+
+    def __init__(self, name, sink=None, cat="pipeline", **attrs):
         self.name = name
         self.cat = cat
         self._sink = sink
         self._attrs = attrs
-        self.elapsed_s = 0.0
+        self._child = 0
 
     def __enter__(self):
-        stack = self._tracer._stack()
-        if stack:
-            self._attrs = dict(self._attrs or ())
-            self._attrs.setdefault("parent", stack[-1])
-        stack.append(self.name)
-        self._t0_ns = time.perf_counter_ns()
+        state = self._state = _local.state
+        state.stack.append(self)
+        tm = _trace_me
+        if tm is None:
+            tm = _resolve_trace_me()
+        if tm and tm.is_enabled():
+            self._tm = tm(self.name)
+            self._tm.__enter__()
+        else:
+            self._tm = None
+        self._t0 = _perf_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur_ns = time.perf_counter_ns() - self._t0_ns
-        stack = self._tracer._stack()
-        if stack and stack[-1] == self.name:
+        dur = _perf_ns() - self._t0
+        if self._tm is not None:
+            self._tm.__exit__(exc_type, exc, tb)
+        state = self._state
+        stack = state.stack
+        if stack and stack[-1] is self:
             stack.pop()
-        self.elapsed_s = dur_ns / 1e9
+        elif self in stack:
+            stack.remove(self)
+        loan = state.loan
+        if loan is None or loan.live:
+            parent = stack[-1] if stack else state.base
+            record = state.record
+        else:
+            parent = record = None
+        if parent is not None:
+            parent._child += dur
+        if record is not None:
+            table = record.self_ns
+            table[self.name] = table.get(self.name, 0) + dur - self._child
+            record.spans += 1
+        self.elapsed_s = dur / 1e9
         if self._sink is not None:
             obj, field = self._sink
             setattr(obj, field, getattr(obj, field) + self.elapsed_s)
-        if exc_type is not None:
-            self._attrs = dict(self._attrs or ())
-            self._attrs["error"] = exc_type.__name__
-        self._tracer._record_span(
-            self.name, self.cat, self._t0_ns, dur_ns, self._attrs
-        )
+        tracer = _tracer
+        if tracer.enabled:
+            attrs = self._attrs
+            if parent is not None or exc_type is not None:
+                attrs = dict(attrs)
+                if parent is not None:
+                    attrs.setdefault("parent", parent.name)
+                if exc_type is not None:
+                    attrs["error"] = exc_type.__name__
+            tracer._record_span(self.name, self.cat, self._t0, dur, attrs)
         return False
+
+
+span = Span
+
+
+class _WaitSpan(Span):
+    """``device.wait``: its self time also goes to its site's total."""
+
+    __slots__ = ()
+
+    def __exit__(self, exc_type, exc, tb):
+        Span.__exit__(self, exc_type, exc, tb)
+        _, record = _attribution(self._state)
+        if record is not None:
+            site = self._attrs["site"]
+            waits = record.wait_ns
+            waits[site] = (waits.get(site, 0)
+                           + round(self.elapsed_s * 1e9) - self._child)
+        return False
+
+
+class _AnalysisSpan(Span):
+    """Root span of an analysis: opens this thread's record on entry
+    (unless one is open already: then it is a plain span) and closes it
+    into the ring on exit."""
+
+    __slots__ = ("_record",)
+
+    def __enter__(self):
+        state = _local.state
+        self._record = None
+        if state.record is None:
+            self._record = state.record = AnalysisRecord(self.name)
+        return Span.__enter__(self)
+
+    def __exit__(self, exc_type, exc, tb):
+        Span.__exit__(self, exc_type, exc, tb)
+        record = self._record
+        if record is not None:
+            record.wall_s = self.elapsed_s
+            record.trace_id = _tracer.trace_id
+            self._state.record = None
+            _profiles.close(record)
+        return False
+
+
+class Loan:
+    """A thread's open span and record, lent to a thread that works
+    while the lender waits for it.  Revoked when the lender stops
+    waiting (a watchdog deadline): the borrower's later spans then
+    count nowhere."""
+
+    __slots__ = ("parent", "record", "live")
+
+    def __init__(self, parent, record):
+        self.parent = parent
+        self.record = record
+        self.live = True
+
+    def revoke(self) -> None:
+        self.live = False
+
+
+def lend() -> Optional[Loan]:
+    """This thread's open span and record, for :func:`borrow` on the
+    thread it is about to wait for (None when neither is open)."""
+    parent, record = _attribution(_local.state)
+    if parent is None and record is None:
+        return None
+    return Loan(parent, record)
+
+
+def borrow(loan: Optional[Loan]) -> None:
+    """Run this (fresh) thread's spans under ``loan``: they nest under
+    the lender's open span and count in its analysis."""
+    if loan is None:
+        return
+    state = _local.state
+    state.base, state.record, state.loan = loan.parent, loan.record, loan
+
+
+def wait(site: str, **attrs) -> Span:
+    """A ``device.wait`` span: the thread blocks here on the device (a
+    result fetch, an upload that can queue behind a running kernel) or
+    on something it shares with the prefetch worker.  ``site`` names
+    the place; the record keeps the wait seconds of each site."""
+    return _WaitSpan("device.wait", cat="device", site=site, **attrs)
+
+
+def analysis(name: str = "cli.analyze", cat: str = "cli") -> Span:
+    """The root span of one analysis: its record opens here and closes
+    into :func:`analysis_profiles` when the span exits."""
+    return _AnalysisSpan(name, cat=cat)
+
+
+def complete_child(name: str, seconds: float, cat: str = "device") -> None:
+    """Account ``seconds`` that just ended on this thread as a child
+    span ``name`` of the innermost open span (a compile that
+    ``jax.monitoring`` reports once it is over: ``device.compile``)."""
+    dur = int(seconds * 1e9)
+    parent, record = _attribution(_local.state)
+    if parent is not None:
+        parent._child += dur
+    if record is not None:
+        record.add(name, dur)
+    tracer = _tracer
+    if tracer.enabled:
+        attrs = {"parent": parent.name} if parent is not None else None
+        tracer._record_span(name, cat, _perf_ns() - dur, dur, attrs)
+
+
+def record_count(key: str, amount: int = 1) -> None:
+    """Add ``amount`` to count ``key`` of the analysis open on this
+    thread (no-op outside an analysis)."""
+    _, record = _attribution(_local.state)
+    if record is not None:
+        record.counts[key] = record.counts.get(key, 0) + amount
+
+
+def current_analysis() -> Optional[AnalysisRecord]:
+    """The record of the analysis open on this thread, if any."""
+    return _attribution(_local.state)[1]
+
+
+def analysis_profiles() -> List[dict]:
+    """The closed analysis records, oldest first (at most
+    ``PROFILE_CAP``)."""
+    with _profiles.lock:
+        records = list(_profiles.records)
+    with _record_lock:
+        return [record.as_dict() for record in records]
+
+
+def self_seconds_totals() -> Dict[str, float]:
+    """Self seconds by span name, summed over every closed record."""
+    with _profiles.lock:
+        return {name: ns / 1e9
+                for name, ns in _profiles.self_total_ns.items()}
+
+
+def profile_counts() -> Dict[str, int]:
+    """Analyses closed and spans counted in them, process lifetime."""
+    with _profiles.lock:
+        return {"analyses": _profiles.closed, "spans": _profiles.spans}
+
+
+# -- the event tracer ----------------------------------------------------
 
 
 class Tracer:
@@ -168,16 +449,11 @@ class Tracer:
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._tls = threading.local()
         self._epoch_ns = time.perf_counter_ns()
         self._events = []
         self._cap = _env_cap()
         self._totals: Dict[str, float] = {}  # name -> cumulative seconds
         self._counts: Dict[str, int] = {}    # name -> completed spans
-        # advisory live view: thread ident -> that thread's span stack
-        # (the list object itself; registered once per thread, read by
-        # the /debug/requests endpoint without touching the hot path)
-        self._all_stacks: Dict[int, list] = {}
         # cross-process trace identity: minted at the CLI/serve edge,
         # propagated through the coalescer scope stamps and the fleet
         # lease protocol so one request renders as one Perfetto trace
@@ -189,24 +465,20 @@ class Tracer:
         self.span_count = 0
         self.instant_count = 0
         self.dropped = 0
-        self.record_events = True
         # enabled only on an explicit opt-in: env MYTHRIL_TPU_TRACE
         # truthy, --trace-out (observability.configure_from_cli), or a
-        # programmatic enable() (bench.py).  The kill switch wins.
+        # programmatic enable().  The kill switch wins.
         env = os.environ.get("MYTHRIL_TPU_TRACE", "").lower()
         self.enabled = env in ("1", "on", "true") and not _kill_switched()
 
     # -- control -------------------------------------------------------
 
-    def enable(self, record_events: bool = True) -> bool:
-        """Turn tracing on (False when the ``MYTHRIL_TPU_TRACE=0`` kill
-        switch vetoes it).  ``record_events=False`` keeps only the
-        per-name totals/counts (bench mode: the phase breakdown without
-        the event buffer)."""
+    def enable(self) -> bool:
+        """Turn event recording on (False when the
+        ``MYTHRIL_TPU_TRACE=0`` kill switch vetoes it)."""
         if _kill_switched():
             return False
         self.enabled = True
-        self.record_events = record_events
         return True
 
     def disable(self) -> None:
@@ -225,25 +497,25 @@ class Tracer:
 
     # -- recording -----------------------------------------------------
 
-    def _stack(self):
-        stack = getattr(self._tls, "stack", None)
-        if stack is None:
-            stack = self._tls.stack = []
-            # one dict write per thread lifetime: the live-span view
-            # (/debug/requests "phase") reads these lists advisorily
-            self._all_stacks[threading.get_ident()] = stack
-        return stack
-
     def live_spans(self) -> Dict[int, str]:
         """Advisory snapshot of each thread's innermost open span name
         (the serve plane's ``/debug/requests`` phase field).  Reads the
         per-thread stacks without locking — a torn read can at worst
         name a span that just closed."""
         out = {}
-        for tid, stack in list(self._all_stacks.items()):
-            if stack:
-                out[tid] = stack[-1]
+        for tid, stack in list(_all_stacks.items()):
+            try:
+                out[tid] = stack[-1].name
+            except IndexError:  # no open span, or it just closed
+                pass
         return out
+
+    def _append(self, event) -> None:
+        # callers hold self._lock
+        if len(self._events) < self._cap:
+            self._events.append(event)
+        else:
+            self.dropped += 1
 
     def _record_span(self, name, cat, t0_ns, dur_ns, attrs) -> None:
         event = {
@@ -261,11 +533,7 @@ class Tracer:
             self.span_count += 1
             self._totals[name] = self._totals.get(name, 0.0) + dur_ns / 1e9
             self._counts[name] = self._counts.get(name, 0) + 1
-            if self.record_events:
-                if len(self._events) < self._cap:
-                    self._events.append(event)
-                else:
-                    self.dropped += 1
+            self._append(event)
         from mythril_tpu.observability.flight import get_flight_recorder
 
         get_flight_recorder().record(event)
@@ -284,11 +552,7 @@ class Tracer:
             event["args"] = attrs
         with self._lock:
             self.instant_count += 1
-            if self.record_events:
-                if len(self._events) < self._cap:
-                    self._events.append(event)
-                else:
-                    self.dropped += 1
+            self._append(event)
         from mythril_tpu.observability.flight import get_flight_recorder
 
         get_flight_recorder().record(event)
@@ -307,20 +571,16 @@ class Tracer:
             "args": {k: float(v) for k, v in values.items()},
         }
         with self._lock:
-            if self.record_events:
-                if len(self._events) < self._cap:
-                    self._events.append(event)
-                else:
-                    self.dropped += 1
+            self._append(event)
 
     def absorb_events(self, events: list, worker: Optional[str] = None,
                       trace_id: Optional[str] = None) -> int:
         """Merge pre-built trace events from another process (a fleet
         worker's span stream) into the buffer so ``--trace-out``
         renders one timeline.  Per-name *totals* are deliberately NOT
-        updated: the phase buckets (cone/sweep/tail...) describe THIS
-        process's wall, and folding a worker's spans in would
-        double-count time the coordinator spent waiting on it.
+        updated: they describe THIS process's wall, and folding a
+        worker's spans in would double-count time the coordinator spent
+        waiting on it.
 
         ``worker`` names the stream: its events are re-pidded onto a
         synthetic pid unique to that worker, so a respawned worker that
@@ -340,9 +600,7 @@ class Tracer:
                     # from a range no OS hands out, one per worker name
                     remap_pid = 1_000_000 + len(self._absorbed_pids) + 1
                     self._absorbed_pids[worker] = remap_pid
-                    if self.record_events and len(self._events) < (
-                        self._cap
-                    ):
+                    if len(self._events) < self._cap:
                         label = f"fleet-worker {worker}"
                         if trace_id:
                             label += f" [trace {trace_id}]"
@@ -363,8 +621,7 @@ class Tracer:
                         args["trace_id"] = trace_id
                         event["args"] = args
                 if len(self._events) < self._cap:
-                    if self.record_events:
-                        self._events.append(event)
+                    self._events.append(event)
                     self.span_count += int(event.get("ph") == "X")
                     self.instant_count += int(event.get("ph") == "i")
                     absorbed += 1
@@ -389,7 +646,8 @@ class Tracer:
             return list(self._events)
 
     def totals_snapshot(self) -> Dict[str, float]:
-        """Per-name cumulative span seconds (copy)."""
+        """Per-name cumulative span seconds, children included, of the
+        spans recorded while tracing was on (copy)."""
         with self._lock:
             return dict(self._totals)
 
@@ -400,7 +658,8 @@ class Tracer:
     def export_chrome(self, path: str) -> str:
         """Write the Chrome/Perfetto ``trace_event`` JSON.  The object
         form (``{"traceEvents": [...]}``) is used so metadata rides
-        alongside without breaking loaders.  When the buffer cap
+        alongside without breaking loaders: ``otherData.analyses``
+        holds the closed analysis records.  When the buffer cap
         dropped events, a ``trace.truncated`` instant marks the
         timeline itself — a consumer must not mistake a capped trace
         for a complete one (the registry's
@@ -427,6 +686,7 @@ class Tracer:
                 "instant_events": self.instant_count,
                 "dropped_events": self.dropped,
                 "trace_id": self.trace_id,
+                "analyses": analysis_profiles(),
             },
         }
         tmp = path + ".tmp"
@@ -441,21 +701,6 @@ _tracer = Tracer()
 
 def get_tracer() -> Tracer:
     return _tracer
-
-
-def span(name: str, sink=None, cat: str = "pipeline", **attrs):
-    """Context manager timing a named region.
-
-    Disabled + no sink: returns the shared no-op singleton (one
-    attribute check, no allocation).  ``sink=(obj, field)`` always
-    times and accumulates ``field += elapsed`` on exit — use it where a
-    wall-clock counter must keep working with tracing off."""
-    tracer = _tracer
-    if not tracer.enabled:
-        if sink is None:
-            return _NOOP
-        return _StatSpan(sink)
-    return _Span(tracer, name, cat, sink, attrs)
 
 
 def instant(name: str, cat: str = "event", **attrs) -> None:
@@ -511,27 +756,7 @@ def totals_snapshot() -> Dict[str, float]:
     return _tracer.totals_snapshot()
 
 
-def phase_totals(totals: Optional[Dict[str, float]] = None,
-                 base: Optional[Dict[str, float]] = None) -> Dict[str, float]:
-    """Fold per-name span totals into the bench phase buckets
-    (cone/upload/sweep/tail seconds).  ``base`` subtracts an earlier
-    :func:`totals_snapshot` so callers can scope the breakdown to one
-    contract."""
-    if totals is None:
-        totals = _tracer.totals_snapshot()
-    out = {key: 0.0 for key in PHASE_KEYS}
-    for name, seconds in totals.items():
-        if base:
-            seconds -= base.get(name, 0.0)
-        if seconds <= 0.0:
-            continue
-        for prefix, key in PHASE_PREFIXES:
-            if name.startswith(prefix):
-                out[key] += seconds
-                break
-    return {f"{key}_s": round(value, 4) for key, value in out.items()}
-
-
 def reset_for_tests() -> None:
-    global _tracer
+    global _tracer, _profiles
     _tracer = Tracer()
+    _profiles = _Profiles()

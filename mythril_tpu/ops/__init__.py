@@ -37,6 +37,11 @@ def configure_jax() -> None:
     _jax_configured = True
     import jax
 
+    from mythril_tpu.resilience.watchdog import compile_clock
+
+    # compile seconds are counted per thread (and per analysis record)
+    # from the first compile on
+    compile_clock()
     if jax.default_backend() == "cpu":
         return
     jax.config.update("jax_compilation_cache_dir", compile_cache_dir())

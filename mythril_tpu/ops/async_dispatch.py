@@ -28,6 +28,8 @@ from typing import List, Optional
 
 import numpy as np
 
+from mythril_tpu.observability import spans as obs
+
 log = logging.getLogger(__name__)
 
 
@@ -94,16 +96,29 @@ class AsyncDispatcher:
             "constraint_sets": list(rep_constraint_sets),
         }
 
+        # the launching analysis is charged the seconds its prefetch is
+        # in flight, also where the kernel ends after the analysis did;
+        # the worker's own spans stay out of every analysis record
+        record = obs.current_analysis()
+        if record is not None:
+            record.prefetch_launched()
+
         def work():
             try:
                 from mythril_tpu.resilience import faults
 
-                faults.maybe_fault_prefetch()
-                handle = runner()
-                # block on the worker, never on the host: done=True
-                # only after the kernel finished, so harvest's
-                # np.asarray is a pure copy on every jax version
-                handle["status"].block_until_ready()
+                try:
+                    with obs.span("prefetch.launch", cat="prefetch"):
+                        faults.maybe_fault_prefetch()
+                        handle = runner()
+                    # block on the worker, never on the host: done=True
+                    # only after the kernel finished, so harvest's
+                    # np.asarray is a pure copy on every jax version
+                    with obs.span("prefetch.wait", cat="prefetch"):
+                        handle["status"].block_until_ready()
+                finally:
+                    if record is not None:
+                        record.prefetch_done()
                 pending["status"] = handle["status"]
                 if "cone_vars" in handle:
                     # cone-tier runner: expand the compact assignment
@@ -185,8 +200,9 @@ class AsyncDispatcher:
         pending, self.pending = self.pending, None
         from mythril_tpu.smt import terms as T
 
-        status = np.asarray(pending["status"])
-        assign = np.asarray(pending["assign"])
+        with obs.wait("prefetch.harvest"):
+            status = np.asarray(pending["status"])
+            assign = np.asarray(pending["assign"])
         from mythril_tpu.ops.batched_sat import _env_from_assignment
 
         from mythril_tpu.support.support_args import args as _args

@@ -28,6 +28,7 @@ Sharding: the lane axis is data-parallel; ``parallel.mesh`` shards
 (broadcast once over ICI) — see parallel/mesh.py.
 """
 
+import contextlib
 import logging
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -238,6 +239,11 @@ class DispatchStats:
         # the dispatches_per_analysis headline (the host round-trip
         # cost the resident kernel exists to kill)
         self.device_dispatch_calls = 0
+        # frontiers the adaptive fuse kept off the device (a periodic
+        # retry dispatches, so it is not one): unlike the backend's
+        # fused_skips, which restarts per solver context and at each
+        # re-arm, this count restarts only with dispatch_stats
+        self.fuse_skips = 0
         # resident-kernel dispatches and their exit taxonomy (device-
         # decided: all lanes retired / iteration budget exhausted /
         # device-side stall watchdog tripped) — profile_t3 reports the
@@ -376,7 +382,7 @@ class DevicePool:
         # (the mesh path shards from here without a device round-trip)
         stale = self.lits
         with obs.span("upload.pool", cat="h2d", bytes=int(mat.nbytes),
-                      rows=target_c):
+                      rows=target_c), obs.wait("upload.pool"):
             self.lits = jnp.asarray(self.lits_np)
         if stale is not None and self._safe_to_donate():
             # donate the stale device buffer eagerly: a refresh doubles
@@ -425,7 +431,8 @@ class DevicePool:
         if len(rows):
             self.lits_np[self.filled : self.filled + len(rows)] = rows
             with obs.span("upload.delta", cat="h2d",
-                          bytes=int(rows.nbytes), rows=len(rows)):
+                          bytes=int(rows.nbytes), rows=len(rows)), \
+                    obs.wait("upload.delta"):
                 self.lits = self.lits.at[
                     self.filled : self.filled + len(rows)
                 ].set(rows)
@@ -459,7 +466,8 @@ class DevicePool:
                 self.lits_np[: self.filled], self.num_vars + 1
             )
         with obs.span("upload.adjacency", cat="h2d",
-                      bytes=int(self._adj_np.nbytes)):
+                      bytes=int(self._adj_np.nbytes)), \
+                obs.wait("upload.adjacency"):
             self._adj_dev = jnp.asarray(self._adj_np)
         dispatch_stats.h2d_bytes += int(self._adj_np.nbytes)
         return self._adj_dev
@@ -1038,18 +1046,30 @@ class BatchedSatBackend:
         dispatch_stats.learned_clauses += accepted
 
     def _cached(self, key, build):
-        with self._step_lock:
+        with self._holding_step_lock():
             step = self._step_cache.get(key)
             if step is not None:
                 return step
         built = build()
-        with self._step_lock:
+        with self._holding_step_lock():
             step = self._step_cache.setdefault(key, built)
             if len(self._step_cache) > 12:
                 for stale in list(self._step_cache):
                     if stale != key and len(self._step_cache) > 12:
                         del self._step_cache[stale]
         return step
+
+    @contextlib.contextmanager
+    def _holding_step_lock(self):
+        """The step-cache lock, shared with the async prefetch worker:
+        a thread that finds it held waits in a ``device.wait``."""
+        if not self._step_lock.acquire(blocking=False):
+            with obs.wait("step_lock"):
+                self._step_lock.acquire()
+        try:
+            yield
+        finally:
+            self._step_lock.release()
 
     def _solve_resident(self, key_base: str, lits, assign, pref=None,
                         frontier=None):
@@ -1120,9 +1140,10 @@ class BatchedSatBackend:
         budget = RK.resident_budget()
         watchdog_limit = RK.resident_watchdog_limit()
         n_lane = len(RK.RESIDENT_LANE_FIELDS)
-        shared0 = [
-            jnp.asarray(state[k]) for k in RK.RESIDENT_SHARED_FIELDS
-        ]
+        with obs.wait("upload.lanes"):
+            shared0 = [
+                jnp.asarray(state[k]) for k in RK.RESIDENT_SHARED_FIELDS
+            ]
         status_idx = RK.RESIDENT_LANE_FIELDS.index("status")
 
         def step_fn(lits_, *lane_vals):
@@ -1131,12 +1152,12 @@ class BatchedSatBackend:
             # exit-reason telemetry per completed kernel invocation
             # (np.asarray blocks until the kernel finished — the wedge
             # point, so it stays inside the supervised region)
-            reason = RK.exit_reason(
-                np.asarray(lane_out[status_idx]),
-                int(np.asarray(shared_out[2])[0]),
-                int(np.asarray(shared_out[3])[0]),
-                watchdog_limit, budget,
-            )
+            with obs.wait("resident.fetch"):
+                status = np.asarray(lane_out[status_idx])
+                stall = int(np.asarray(shared_out[2])[0])
+                iterations = int(np.asarray(shared_out[3])[0])
+            reason = RK.exit_reason(status, stall, iterations,
+                                    watchdog_limit, budget)
             dispatch_stats.resident_dispatches += 1
             counter = f"resident_exit_{reason}"
             setattr(dispatch_stats, counter,
@@ -1428,7 +1449,8 @@ class BatchedSatBackend:
         dog = get_watchdog()
 
         def attempt(sub_state, sub_ids, retries=None):
-            vals = [jnp.asarray(sub_state[k]) for k in order]
+            with obs.wait("upload.lanes"):
+                vals = [jnp.asarray(sub_state[k]) for k in order]
 
             def _thunk():
                 faults.maybe_fault_dispatch(lane_ids=sub_ids)
@@ -1443,7 +1465,8 @@ class BatchedSatBackend:
                 # wedge point, so it belongs inside the supervision
                 # (np.array, not asarray: the ladder mutates the state
                 # between rounds and jax exports read-only views)
-                return [np.array(o) for o in out]
+                with obs.wait("round.fetch"):
+                    return [np.array(o) for o in out]
 
             return dict(zip(order, dog.run_attempts(
                 key, _thunk, retries=retries
@@ -1667,7 +1690,8 @@ class BatchedSatBackend:
             def _upload_rows():
                 dispatch_stats.h2d_bytes += int(rows.nbytes)
                 with obs.span("upload.cone_rows", cat="h2d",
-                              bytes=int(rows.nbytes)):
+                              bytes=int(rows.nbytes)), \
+                        obs.wait("upload.cone_rows"):
                     return jnp.asarray(rows)
 
             rows_dev = get_cone_memo().get_or_build(
@@ -1688,7 +1712,8 @@ class BatchedSatBackend:
                     adj = build_adjacency(rows, assign.shape[1])
                     dispatch_stats.h2d_bytes += int(adj.nbytes)
                     with obs.span("upload.adjacency", cat="h2d",
-                                  bytes=int(adj.nbytes)):
+                                  bytes=int(adj.nbytes)), \
+                            obs.wait("upload.adjacency"):
                         return jnp.asarray(adj)
 
                 adj_dev = get_cone_memo().get_or_build(
@@ -1840,13 +1865,15 @@ class BatchedSatBackend:
                     axis=1,
                 )
             full_width = ctx.solver.num_vars + 1
+            # counted here, at the launch, on the analysis thread: the
+            # worker may get to its run after this analysis is over
+            dispatch_stats.h2d_bytes += int(rows.nbytes)
+            dispatch_stats.device_dispatch_calls += 1
 
             def run_cone():
                 step = self._cached_step(bucket)
                 # worker-thread upload (never through the shared memo:
                 # the host could be mutating it concurrently)
-                dispatch_stats.h2d_bytes += int(rows.nbytes)
-                dispatch_stats.device_dispatch_calls += 1
                 assign_dev, status_dev = step(
                     jnp.asarray(rows), jnp.asarray(assign)
                 )
@@ -1866,12 +1893,12 @@ class BatchedSatBackend:
         assign = self._sync_pool_and_assign(ctx, assumption_sets, num_vars)
         bucket = self.pool.num_vars
         lits = self.pool.lits  # immutable jax array: safe to capture
+        dispatch_stats.device_dispatch_calls += 1  # at the launch
 
         def run():
             # first compile for this bucket happens on the worker
             # thread — the host's only budget here is idle time
             step = self._cached_step(bucket)
-            dispatch_stats.device_dispatch_calls += 1
             assign_dev, status_dev = step(lits, jnp.asarray(assign))
             return {"status": status_dev, "assign": assign_dev}
 
@@ -2212,6 +2239,8 @@ def _batch_check_states_inner(ctx, constraint_sets, lanes_led):
             or backend.fuse_retries >= MAX_FUSE_RETRIES
             or backend.fused_skips % FUSE_RETRY_PERIOD != 0
         ):
+            dispatch_stats.fuse_skips += 1
+            obs.record_count("fuse_skips")
             return decided
         backend.fuse_retries += 1
         fuse_retry_attempt = True

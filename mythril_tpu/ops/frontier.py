@@ -360,9 +360,10 @@ def build_frontier_rounds(num_vars: int, budget: int,
     iters = budget * FRONTIER_BUDGET_MULT
     scan_rows = make_scan_rows(V1)
 
-    def rounds(lits, adj, assign0, lvl0, reason0, tpos0, dvar0, dphase0,
-               dflip0, depth0, status0, stamp0, recent0, cspos0,
-               csneg0, fullsw0, fsteps0, nlearn0, learned0, pref0):
+    def frontier_rounds(lits, adj, assign0, lvl0, reason0, tpos0, dvar0,
+                        dphase0, dflip0, depth0, status0, stamp0, recent0,
+                        cspos0, csneg0, fullsw0, fsteps0, nlearn0, learned0,
+                        pref0):
         B = assign0.shape[0]
         C, K = lits.shape
         deg = adj.shape[1]
@@ -601,7 +602,7 @@ def build_frontier_rounds(num_vars: int, budget: int,
         out = lax.while_loop(cond, body, init)
         return out[:-1] + (pref0,)
 
-    return rounds
+    return frontier_rounds
 
 
 def make_frontier_round_step(num_vars: int, budget: int,

@@ -636,8 +636,8 @@ def _dpll_round_loop(sweep, B, V, budget, max_decisions, sweep_hot=None,
     D = max(1, min(max_decisions, V))  # stack planes ([B, D])
     tiered = sweep_hot is not None and tier_period > 1
 
-    def rounds(P, N, width, A0, lvl0, dvar0, dphase0, dflip0, dbulk0,
-               depth0, status0, taint0, active0, pref0):
+    def dense_rounds(P, N, width, A0, lvl0, dvar0, dphase0, dflip0,
+                     dbulk0, depth0, status0, taint0, active0, pref0):
         col = lax.broadcasted_iota(jnp.int32, (B, V), 1)
         dcol = lax.broadcasted_iota(jnp.int32, (B, D), 1)  # slot l ↔ level l+1
         krow = jnp.arange(DPLL_BULK_K)[None, :]            # [1, K]
@@ -824,7 +824,7 @@ def _dpll_round_loop(sweep, B, V, budget, max_decisions, sweep_hot=None,
         out = lax.while_loop(cond, body, init)
         return out[:-1] + (out[-1],)
 
-    return rounds
+    return dense_rounds
 
 
 def _dpll_solve_loop(sweep, B, V, steps, max_decisions):
@@ -1123,7 +1123,8 @@ def _run_dense_ladder(
             fn = round_fn(B, budget, hot_c)
             dispatch_stats.device_dispatch_calls += 1
             out = fn(*planes, *state)
-            state, steps_used = list(out[:-1]), int(out[-1])
+            with obs.wait("dense.fetch"):
+                state, steps_used = list(out[:-1]), int(out[-1])
         dispatch_stats.rounds += 1
         dispatch_stats.device_sweeps += steps_used
         dispatch_stats.lane_sweeps_total += steps_used * B

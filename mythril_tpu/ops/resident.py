@@ -223,6 +223,9 @@ def build_resident_rounds(num_vars: int, budget: int,
     E = extra_cap
     scan_rows = make_scan_rows(V1)
 
+    # jitted as the XLA module jit_rounds; the frontier and dense round
+    # kernels have names of their own, so jit_rounds in a profile is
+    # this kernel alone
     def rounds(lits, adj, assign0, lvl0, reason0, tpos0, dvar0, dphase0,
                dflip0, depth0, status0, stamp0, recent0, cspos0,
                csneg0, fullsw0, fsteps0, nlearn0, learned0, pref0,

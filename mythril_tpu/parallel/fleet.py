@@ -430,8 +430,7 @@ def run_fleet(laser, address: int, tx_index: int) -> bool:
         "execution_timeout": int(laser.execution_timeout or 0) or None,
         "create_timeout": int(laser.create_timeout or 0) or None,
         "args": _args_snapshot(),
-        "trace": bool(obs.get_tracer().enabled
-                      and obs.get_tracer().record_events),
+        "trace": bool(obs.get_tracer().enabled),
         # the request/run trace identity crosses the process boundary
         # in the lease payload: workers stamp their span streams with
         # it and the coordinator re-parents them under it on absorb,
@@ -804,7 +803,7 @@ def _worker_run_lease(session: _WorkerSession, header: dict,
         session.wire_dir = journal_dir
     tracer = obs.get_tracer()
     if payload.get("trace"):
-        tracer.enable(record_events=True)
+        tracer.enable()
         tracer.reset()
     # adopt the coordinator's trace identity: this worker's spans and
     # lane records belong to the same request timeline

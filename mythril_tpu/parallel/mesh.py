@@ -20,6 +20,8 @@ from typing import Tuple
 
 import numpy as np
 
+from mythril_tpu.observability import spans as obs
+
 log = logging.getLogger(__name__)
 
 # DPLL budgets for the sharded path: every sweep costs a psum over cp,
@@ -193,7 +195,8 @@ def sharded_frontier_solve(
         _solve_cache.clear()  # one live shape per mesh is enough
         _solve_cache[cache_key] = solve
     final_assign, status = solve(*place_on_mesh(mesh, lits, assign))
-    return (
-        np.asarray(final_assign)[:batch, :true_v1],
-        np.asarray(status)[:batch],
-    )
+    with obs.wait("mesh.fetch"):
+        return (
+            np.asarray(final_assign)[:batch, :true_v1],
+            np.asarray(status)[:batch],
+        )

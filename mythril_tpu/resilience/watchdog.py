@@ -142,10 +142,14 @@ class _CompileClock:
             if depth < 0:
                 return
             self._depth[tid] = depth
-            if not depth:
-                took = time.monotonic() - self._since.pop(tid)
-                self._spent[tid] = self._spent.get(tid, 0.0) + took
-                self._closed += took
+            if depth:
+                return
+            took = time.monotonic() - self._since.pop(tid)
+            self._spent[tid] = self._spent.get(tid, 0.0) + took
+            self._closed += took
+        # the compile is a child of whatever span it ran in: the open
+        # analysis record counts it as device.compile seconds
+        obs.complete_child("device.compile", took)
 
     def seconds(self, tid: int) -> float:
         """Compile seconds of thread ``tid`` so far, open phase included."""
@@ -299,8 +303,12 @@ class DispatchWatchdog:
         clock = compile_clock()
         cancel = threading.Event()
         box: dict = {}
+        # this thread only waits for the worker: the worker's spans nest
+        # under this thread's open span and count in its analysis
+        loan = obs.lend()
 
         def work():
+            obs.borrow(loan)
             _tls.cancel_event = cancel
             try:
                 box["result"] = thunk()
@@ -322,6 +330,8 @@ class DispatchWatchdog:
                 break
         compiling = clock.seconds(thread.ident)
         clock.forget(thread.ident)
+        if loan is not None:
+            loan.revoke()
         if thread.is_alive():
             cancel.set()
             raise WatchdogTimeout(

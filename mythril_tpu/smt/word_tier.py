@@ -41,6 +41,7 @@ default 2), ``MYTHRIL_TPU_WORD_MAX_NODES`` (program-size cap, default
 the batched device path — even for small host batches).
 """
 
+import contextlib
 import logging
 import os
 import zlib
@@ -433,13 +434,16 @@ class WordTier:
             )
             conflict = self._forward(prog, bv, tri, conflict, batch, xp)
 
-        conflict_np = np.asarray(conflict)
+        # the jax executor's planes come back from the device
+        with (obs.wait("word.fetch") if xp is not np
+              else contextlib.nullcontext()):
+            conflict_np = np.asarray(conflict)
+            tri_np = {
+                rid: np.asarray(tri[prog.bool_slot[rid]])
+                if rid in prog.bool_slot else None
+                for rid in roots
+            }
         outcomes: List[tuple] = []
-        tri_np = {
-            rid: np.asarray(tri[prog.bool_slot[rid]])
-            if rid in prog.bool_slot else None
-            for rid in roots
-        }
         for lane, nodes in enumerate(lane_nodes):
             if conflict_np[lane]:
                 outcomes.append(("unsat", None))

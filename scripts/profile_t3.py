@@ -1,11 +1,11 @@
 """Profiling harness for the -t3 depth rows (CDCL iteration loop).
 
 Runs one contract at transaction depth 3 with NO execution cap and
-prints the wall, the span-derived phase breakdown (the same spans
-``--trace-out`` exports — observability/spans.py is the single timing
-source, so this output and a trace file can never disagree), the
-solver split, native-CDCL counters, and (with MYTHRIL_CONE_HISTO=1)
-the per-query cone-size histogram.
+prints the wall, the self seconds of each span in the analysis's
+record (the same spans ``--trace-out`` exports — observability/spans.py
+is the single timing source, so this output and a trace file can never
+disagree), the solver split, native-CDCL counters, and (with
+MYTHRIL_CONE_HISTO=1) the per-query cone-size histogram.
 
 Usage:  JAX_PLATFORMS=cpu python scripts/profile_t3.py \
             [ether_send|overflow|batchtoken] [timeout_s] \
@@ -58,10 +58,11 @@ def main() -> None:
     for key, value in bench.MODES["full"].items():
         setattr(args, key, value)
 
-    # same span plane as bench.py / --trace-out: totals-only unless a
-    # trace file was requested (honors MYTHRIL_TPU_TRACE=0)
+    # the event timeline only when a trace file was requested (honors
+    # MYTHRIL_TPU_TRACE=0); the analysis record is always kept
     tracer = obs_spans.get_tracer()
-    tracer.enable(record_events=trace_out is not None)
+    if trace_out is not None:
+        tracer.enable()
 
     bench.DEVICE_STATUS = "cpu-only"
     t0 = time.time()
@@ -70,9 +71,12 @@ def main() -> None:
     )
     row["total_wall_s"] = round(time.time() - t0, 2)
     row["expected_ok"] = bool(expected & found)
-    # span totals by name (top 12 by wall) — the raw data behind the
-    # row's span_{cone,upload,sweep,tail}_s fields
-    totals = tracer.totals_snapshot()
+    # self seconds by span name (top 12), from the row's analysis
+    # record; fleet workers' lease walls ride the tracer's totals
+    totals = dict(row["span_self_s"])
+    totals.update({name: seconds
+                   for name, seconds in tracer.totals_snapshot().items()
+                   if name.startswith("fleet.worker:")})
     row["span_totals_s"] = {
         name: round(seconds, 3)
         for name, seconds in sorted(

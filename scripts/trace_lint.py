@@ -217,7 +217,7 @@ def _selftest() -> int:
     spans.reset_for_tests()
     ledger_mod.reset_for_tests()
     tracer = spans.get_tracer()
-    tracer.enable(record_events=True)
+    tracer.enable()
     spans.set_trace_id(spans.new_trace_id())
     with spans.span("selftest.outer"):
         spans.instant("selftest.tick")

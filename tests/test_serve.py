@@ -625,7 +625,7 @@ def test_drain_flushes_trace_and_metrics_artifacts(tmp_path):
     saved = (args.trace_out, args.metrics_out)
     args.trace_out, args.metrics_out = trace_out, metrics_out
     tracer = get_tracer()
-    tracer.enable(record_events=True)
+    tracer.enable()
     try:
         checkpoint.request_drain("artifact-flush-test")
         # the artifacts landed AT DRAIN TIME — a later hard kill can no

@@ -190,7 +190,10 @@ def test_coalescer_disabled_passes_through(monkeypatch):
     assert co.admit(ctx, _sets(3), [None], [None]) == []
 
 
-def test_reset_coalescer_clears_queue():
+def test_reset_coalescer_clears_queue(monkeypatch):
+    # a CLI process's reset: a serve engine started earlier in this
+    # process (another test file) leaves the soft serve-mode reset on
+    monkeypatch.setattr(CO, "_serve_mode", False)
     co = CO.get_coalescer()
     ctx = _Ctx()
     co.admit(ctx, _sets(2), [None], [None])

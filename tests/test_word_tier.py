@@ -567,14 +567,17 @@ def test_checkpoint_resume_invalidates_word_tier():
 
 
 def test_word_span_lands_in_phase_totals():
+    """The tier's spans land in the analysis record's self seconds,
+    tracing on or off."""
     from mythril_tpu.observability import spans
 
     spans.reset_for_tests()
-    tracer = spans.get_tracer()
-    if not tracer.enable():
-        pytest.skip("tracing kill-switched in this environment")
     x = T.var("sp", 256)
-    _decide_one([T.ult(x, T.const(3, 256)), T.ult(T.const(9, 256), x)])
-    phases = spans.phase_totals()
-    assert phases["word_s"] > 0
+    with spans.analysis("cli.analyze"):
+        _decide_one([T.ult(x, T.const(3, 256)),
+                     T.ult(T.const(9, 256), x)])
+    (record,) = spans.analysis_profiles()
+    word_s = sum(s for name, s in record["self_s"].items()
+                 if name.startswith("word."))
+    assert word_s > 0
     spans.reset_for_tests()
