@@ -9,7 +9,8 @@ analysis open on that thread.  The root span of an analysis
 record and closes it into a bounded ring that
 :func:`analysis_profiles` reads: wall, self seconds by span name, host
 waits on the device by site (:func:`wait`), compile seconds, the
-async prefetch's seconds in flight, program counts, and the span count.
+async prefetch's seconds in flight and the prefetches cut between
+rounds, program counts, and the span count.
 The self seconds of one record add up to its wall: each span's time is
 counted once, in the innermost span that covers it.
 
@@ -141,7 +142,8 @@ class AnalysisRecord:
     """Where one analysis's wall went (see the module docstring)."""
 
     __slots__ = ("name", "trace_id", "wall_s", "self_ns", "wait_ns",
-                 "counts", "spans", "prefetch_inflight_s", "_prefetch_since")
+                 "counts", "spans", "prefetch_inflight_s", "_prefetch_since",
+                 "prefetch_preempted")
 
     def __init__(self, name: str):
         self.name = name
@@ -153,6 +155,7 @@ class AnalysisRecord:
         self.spans = 0
         self.prefetch_inflight_s = 0.0
         self._prefetch_since: Optional[float] = None
+        self.prefetch_preempted = 0
 
     def add(self, name: str, self_ns: int) -> None:
         table = self.self_ns
@@ -165,10 +168,12 @@ class AnalysisRecord:
         with _record_lock:
             self._prefetch_since = time.perf_counter()
 
-    def prefetch_done(self) -> None:
-        """Its kernel completed (or failed): the seconds since the
-        launch count in this record, also after the record closed."""
+    def prefetch_done(self, preempted: bool = False) -> None:
+        """Its kernel completed (or failed, or was ``preempted``: cut
+        between two rounds): the seconds since the launch count in this
+        record, also after the record closed."""
         with _record_lock:
+            self.prefetch_preempted += preempted
             if self._prefetch_since is not None:
                 self.prefetch_inflight_s += (time.perf_counter()
                                              - self._prefetch_since)
@@ -191,6 +196,7 @@ class AnalysisRecord:
             "compile_s": self_s.get("device.compile", 0.0),
             # a prefetch still in flight counts up to now
             "prefetch_inflight_s": self._prefetch_s(),
+            "prefetch_preempted": self.prefetch_preempted,
             "fuse_skips": self.counts.get("fuse_skips", 0),
             "spans": self.spans,
         }
@@ -301,9 +307,16 @@ span = Span
 
 
 class _WaitSpan(Span):
-    """``device.wait``: its self time also goes to its site's total."""
+    """``device.wait``: its self time also goes to its site's total, and
+    entering it calls the wait hook (:func:`set_wait_hook`)."""
 
     __slots__ = ()
+
+    def __enter__(self):
+        hook = _wait_hook
+        if hook is not None:
+            hook()
+        return Span.__enter__(self)
 
     def __exit__(self, exc_type, exc, tb):
         Span.__exit__(self, exc_type, exc, tb)
@@ -382,6 +395,19 @@ def wait(site: str, **attrs) -> Span:
     on something it shares with the prefetch worker.  ``site`` names
     the place; the record keeps the wait seconds of each site."""
     return _WaitSpan("device.wait", cat="device", site=site, **attrs)
+
+
+#: called, on the waiting thread, as each ``device.wait`` span opens
+_wait_hook = None
+
+
+def set_wait_hook(hook) -> None:
+    """Call ``hook()`` on the waiting thread whenever a ``device.wait``
+    span opens, tracing on or off (the async prefetch stops at its next
+    round when a synchronous caller waits on the device); one hook per
+    process, ``None`` removes it."""
+    global _wait_hook
+    _wait_hook = hook
 
 
 def analysis(name: str = "cli.analyze", cat: str = "cli") -> Span:

@@ -18,11 +18,21 @@ only has to beat *idle time*:
 - device models that verify against the terms enter ``recent_models``,
   feeding the word-level probe the same way CDCL models do.
 
-Nothing ever waits: a pending batch whose results never arrive before
-the analysis ends is simply dropped (telemetry: async_dropped).
+The solve runs as a ladder of short rounds (the sync gather ladder's
+64-step round, ``BatchedSatBackend._prefetch_rounds``), and a
+synchronous device wait preempts the prefetch at its next round: XLA
+cannot cancel a running kernel, so without the cut a sync caller would
+queue behind the whole solve.  Every ``device.wait`` span entered on
+another thread than the worker requests the cut, and so does a new
+blast-context generation (the batch's analysis is over); the lanes
+decided by then are harvested as usual, the rest stay undecided, as
+after a budget bail (telemetry: async_preempted, async_rounds).  A
+pending batch whose results never arrive before the analysis ends is
+simply dropped (telemetry: async_dropped).
 """
 
 import logging
+import threading
 import time
 from typing import List, Optional
 
@@ -45,6 +55,8 @@ class AsyncStats:
         self.dropped = 0           # pending batches discarded unread
         self.launch_s = 0.0        # host time spent launching (non-block)
         self.harvest_s = 0.0       # host time spent harvesting
+        self.rounds = 0            # solve rounds run by the worker
+        self.preempted = 0         # batches cut between two rounds
 
     def as_dict(self):
         return {f"async_{k}": v for k, v in self.__dict__.items()}
@@ -66,6 +78,27 @@ class AsyncDispatcher:
         # pending: a dropped batch must still be joinable at exit, or
         # finalization kills the thread mid-XLA (abort, exit 134)
         self._live_thread = None
+        # the batch that worker runs, for the same reason: a dropped
+        # batch's rounds can still be cut
+        self._live_batch = None
+
+    # -- preemption ----------------------------------------------------
+
+    def preempt(self) -> None:
+        """Ask the running worker to stop at its next round boundary;
+        a no-op on the worker's own thread and when none runs.  The
+        ``device.wait`` hook (see :meth:`launch`)."""
+        batch = self._live_batch
+        if (batch is not None and not batch["done"]
+                and batch["worker"] != threading.get_ident()):
+            batch["preempt"] = True
+
+    @staticmethod
+    def _cut(batch) -> bool:
+        from mythril_tpu.smt.solver import live_generation
+
+        return (batch["preempt"]
+                or live_generation() != batch["generation"])
 
     # -- launch --------------------------------------------------------
 
@@ -90,6 +123,8 @@ class AsyncDispatcher:
             "status": None,
             "assign": None,
             "done": False,
+            "preempt": False,
+            "worker": None,
             "began": time.monotonic(),
             "assumption_sets": list(rep_assumption_sets),
             "node_sets": list(rep_node_sets),
@@ -104,42 +139,58 @@ class AsyncDispatcher:
             record.prefetch_launched()
 
         def work():
+            pending["worker"] = threading.get_ident()
             try:
                 from mythril_tpu.resilience import faults
 
+                cut = False
                 try:
                     with obs.span("prefetch.launch", cat="prefetch"):
                         faults.maybe_fault_prefetch()
-                        handle = runner()
-                    # block on the worker, never on the host: done=True
-                    # only after the kernel finished, so harvest's
-                    # np.asarray is a pure copy on every jax version
-                    with obs.span("prefetch.wait", cat="prefetch"):
-                        handle["status"].block_until_ready()
+                        rounds = runner()
+                        handle = next(rounds)
+                    while True:
+                        # block on the worker, never on the host, and
+                        # round by round, so a cut lands between two
+                        # rounds: done=True only once no round runs
+                        with obs.span("prefetch.wait", cat="prefetch"):
+                            handle["status"].block_until_ready()
+                            status = np.asarray(handle["status"])
+                        async_stats.rounds += 1
+                        if handle["last"] or (status != 0).all():
+                            break  # no lane has a step left to take
+                        if self._cut(pending):
+                            cut = True
+                            break
+                        with obs.span("prefetch.launch", cat="prefetch"):
+                            handle = next(rounds)
                 finally:
+                    async_stats.preempted += cut
                     if record is not None:
-                        record.prefetch_done()
-                pending["status"] = handle["status"]
+                        record.prefetch_done(preempted=cut)
+                # a decision-stack bail (3) is undecided, as in the
+                # one-shot solve
+                pending["status"] = np.where(status == 3, 0, status)
+                assign = np.asarray(handle["assign"])
                 if "cone_vars" in handle:
                     # cone-tier runner: expand the compact assignment
                     # back to full var space on the worker thread so
                     # harvest's _env_from_assignment works unchanged
-                    compact = np.asarray(handle["assign"])
                     cone_vars = handle["cone_vars"]
                     full = np.zeros(
-                        (compact.shape[0], handle["full_width"]), np.int8
+                        (assign.shape[0], handle["full_width"]), np.int8
                     )
-                    full[:, cone_vars] = compact[:, 1:cone_vars.size + 1]
-                    pending["assign"] = full
-                else:
-                    pending["assign"] = handle["assign"]
+                    full[:, cone_vars] = assign[:, 1:cone_vars.size + 1]
+                    assign = full
+                pending["assign"] = assign
             except Exception as exc:  # noqa: BLE001 — prefetch only
                 log.debug("async dispatch failed: %s", exc)
                 pending["failed"] = True
             pending["done"] = True
 
-        import threading
-
+        # a synchronous device wait on any other thread cuts the batch
+        obs.set_wait_hook(self.preempt)
+        self._live_batch = pending
         thread = threading.Thread(target=work, daemon=True)
         thread.start()
         self._live_thread = thread
@@ -157,9 +208,9 @@ class AsyncDispatcher:
         return bool(self.pending["done"])
 
     def harvest(self, ctx) -> None:
-        """Consume a finished batch, if any.  Never blocks: a batch
-        still in flight stays pending; a batch from a dead context is
-        dropped."""
+        """Consume a finished batch, if any (one cut short: the lanes
+        its rounds decided).  Never blocks: a batch still in flight
+        stays pending; a batch from a dead context is dropped."""
         if self.pending is None:
             return
         if self.pending["generation"] != ctx.generation:

@@ -672,8 +672,9 @@ def build_solve_lane(
     :func:`build_round_lane` run as a single full-budget round.
 
     This single definition backs the one-shot jit path
-    (``make_solve_step``, used by the async prefetch runner) and the
-    mesh-sharded path (parallel/mesh.py), which passes a
+    (``make_solve_step``, which the async prefetch's sliced rounds
+    reproduce bit for bit) and the mesh-sharded path
+    (parallel/mesh.py), which passes a
     ``reduce_hook(pos, neg, conflict, spos, sneg)`` merging
     forced-literal votes, conflict flags and decision scores across
     clause shards (psum over the ``cp`` mesh axis); the merged
@@ -979,19 +980,45 @@ class BatchedSatBackend:
         )
         return [None] * batch
 
-    def _cached_step(self, bucket: int):
-        """Jitted one-shot solve for a pool bucket, compiled at most
-        once per bucket (thread-safe: shared by the sync path and the
-        async prefetch worker).  Bounded to a few live shapes."""
-        return self._cached(("solve", bucket),
-                            lambda: make_solve_step(bucket))
-
     def _cached_round(self, bucket: int, budget: int):
         """Jitted ladder round for (pool bucket, step budget) — budgets
         come from the fixed GATHER_ROUND_BUDGETS set, so the key space
-        stays a small grid and nothing recompiles after warmup."""
+        stays a small grid and nothing recompiles after warmup.
+        Thread-safe: shared by the sync ladder and the async prefetch
+        worker."""
         return self._cached(("round", bucket, budget),
                             lambda: make_round_step(bucket, budget))
+
+    def _prefetch_rounds(self, bucket: int, lits, assign, **extra):
+        """The async prefetch's GATHER_STEPS-step solve, sliced into
+        rounds of the sync ladder's first budget (the same compiled
+        round), so a synchronous caller can stop it between two rounds.
+
+        A generator: each ``next`` enqueues one round and yields
+        ``{"status", "assign", "last", **extra}`` as device arrays,
+        without waiting for the round.  ``step`` restarts at 0 every
+        round, so each lane runs at most GATHER_STEPS steps in all: run
+        to its last round, the lane states equal ``make_solve_step``'s,
+        bit for bit (statuses raw: 3 is a decision-stack bail)."""
+        _, jnp = _require_jax()
+        budget = GATHER_ROUND_BUDGETS[0]
+        rounds = GATHER_STEPS // budget
+        step_fn = self._cached_round(bucket, budget)
+        batch, V1 = assign.shape
+        D = max(1, min(GATHER_DECISIONS, V1))
+        zero = jnp.zeros(batch, jnp.int32)
+        # (assign, lvl, dvar, dphase, dflip, depth, status, step, pref)
+        state = (
+            jnp.asarray(assign), jnp.zeros((batch, V1), jnp.int32),
+            jnp.zeros((batch, D), jnp.int32), jnp.zeros((batch, D), jnp.int8),
+            jnp.zeros((batch, D), bool), zero, zero, zero,
+            jnp.zeros((batch, V1), jnp.int8),  # no warm-start preference
+        )
+        lits = jnp.asarray(lits)
+        for i in range(rounds):
+            state = step_fn(lits, *state[:7], zero, state[8])
+            yield dict(extra, status=state[6], assign=state[0],
+                       last=i == rounds - 1)
 
     def _cached_frontier_round(self, bucket: int, budget: int):
         """Jitted frontier round (ops/frontier.py) — the cache key
@@ -1838,8 +1865,9 @@ class BatchedSatBackend:
         sync path's eligibility gates (minus the profit gate) and build
         the device inputs ON THE CALLING THREAD — everything that
         touches the blast context — then return a zero-argument runner
-        that compiles (first time per pool bucket) and launches the
-        jitted solve.  The runner is safe to execute on a worker
+        that returns the solve's round generator
+        (:meth:`_prefetch_rounds`; its first round compiles once per
+        pool bucket).  The runner is safe to execute on a worker
         thread: it captures immutable jax arrays and plain numpy, and
         the host thread never waits on it.  Returns None when the
         frontier is ineligible."""
@@ -1854,7 +1882,6 @@ class BatchedSatBackend:
             if built is None:
                 return None
             rows, assign, cone_vars, _roots = built
-            _, jnp = _require_jax()
             n = int(cone_vars.size)
             bucket = DevicePool._bucket(n)
             if bucket + 1 > assign.shape[1]:
@@ -1871,25 +1898,18 @@ class BatchedSatBackend:
             dispatch_stats.device_dispatch_calls += 1
 
             def run_cone():
-                step = self._cached_step(bucket)
                 # worker-thread upload (never through the shared memo:
-                # the host could be mutating it concurrently)
-                assign_dev, status_dev = step(
-                    jnp.asarray(rows), jnp.asarray(assign)
-                )
+                # the host could be mutating it concurrently);
                 # cone_vars/full_width let the harvester expand the
                 # compact assignment back to full var space
-                return {
-                    "status": status_dev,
-                    "assign": assign_dev,
-                    "cone_vars": cone_vars,
-                    "full_width": full_width,
-                }
+                return self._prefetch_rounds(
+                    bucket, rows, assign,
+                    cone_vars=cone_vars, full_width=full_width,
+                )
 
             return run_cone
         if verdict is not None:
             return None
-        _, jnp = _require_jax()
         assign = self._sync_pool_and_assign(ctx, assumption_sets, num_vars)
         bucket = self.pool.num_vars
         lits = self.pool.lits  # immutable jax array: safe to capture
@@ -1898,9 +1918,7 @@ class BatchedSatBackend:
         def run():
             # first compile for this bucket happens on the worker
             # thread — the host's only budget here is idle time
-            step = self._cached_step(bucket)
-            assign_dev, status_dev = step(lits, jnp.asarray(assign))
-            return {"status": status_dev, "assign": assign_dev}
+            return self._prefetch_rounds(bucket, lits, assign)
 
         return run
 

@@ -144,6 +144,12 @@ def get_blast_context() -> BlastContext:
     return _context
 
 
+def live_generation() -> Optional[int]:
+    """The generation of the blast context in use, without making one:
+    None after a reset until the next :func:`get_blast_context`."""
+    return None if _context is None else _context.generation
+
+
 def reset_blast_context() -> None:
     """Drop the CNF pool and the term-interner table (used between
     unrelated analyses and in tests).  Callers must not retain Expression

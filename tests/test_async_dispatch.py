@@ -82,7 +82,6 @@ def test_profit_skip_launches_and_harvest_decides_repeats(monkeypatch):
         assert _time.monotonic() < deadline, "worker thread never finished"
         _time.sleep(0.05)
     assert not dispatcher.pending.get("failed"), "async launch failed"
-    dispatcher.pending["status"].block_until_ready()
 
     # the SAME frontier re-presents (frontiers repeat across rounds):
     # the harvest at entry memoizes refutations, and this round decides
@@ -203,3 +202,190 @@ def test_prefetch_uses_cone_tier_on_oversized_pool(monkeypatch):
     dispatcher.harvest(ctx)
     assert async_stats.harvested == 1
     assert async_stats.unsat > 0, "harvest consumed no cone refutations"
+
+
+# -- preemptible rounds -----------------------------------------------------
+
+V = 255  # vars of the planted CNF below; its pool bucket
+
+
+def _planted_batch():
+    """16 lanes over one planted random 3-CNF (255 vars, 1024 clauses).
+    Lanes fix 0-200 vars, to the planted model (even lanes) or at
+    random (odd lanes), and lane 15 falsifies a clause outright: after
+    GATHER_STEPS they end SAT, UNSAT and undecided, and some decide
+    only after the second round."""
+    from mythril_tpu.ops.batched_sat import MAX_CLAUSE_WIDTH
+
+    rng = np.random.default_rng(0)
+    sol = rng.choice([-1, 1], V + 1)
+    rows = []
+    while len(rows) < 1024:
+        vs = rng.choice(np.arange(2, V + 1), 3, replace=False)
+        sg = rng.choice([-1, 1], 3)
+        if any(sg == sol[vs]):
+            rows.append(list(vs * sg) + [0] * (MAX_CLAUSE_WIDTH - 3))
+    lits = np.array(rows, np.int32)
+    assign = np.zeros((16, V + 1), np.int8)
+    assign[:, :2] = 1  # pad column and the constant-TRUE anchor
+    for lane, k in enumerate((0, 0, 0, 0, 10, 20, 40, 60, 80, 100, 120,
+                              140, 160, 180, 200)):
+        vs = rng.choice(np.arange(2, V + 1), k, replace=False)
+        assign[lane, vs] = sol[vs] if lane % 2 == 0 else rng.choice(
+            [-1, 1], k)
+    for lit in lits[15][:3]:
+        assign[15, abs(lit)] = -np.sign(lit)
+    return lits, assign
+
+
+class _Ctx:
+    """What the runner and the harvest touch of a blast context; the
+    live context's generation, so the batch stays current."""
+
+    def __init__(self):
+        self.generation = get_blast_context().generation
+        self.solver = type("S", (), {"num_vars": V + 9})
+        self.unsat_lanes, self.models = [], []
+
+    def note_unsat(self, node_set):
+        self.unsat_lanes.extend(node_set)
+
+    def learn_nogood(self, assumptions, certified=False):
+        pass
+
+    def _remember_model(self, env, truth=None):
+        self.models.append(truth)
+
+
+def _prefetch(monkeypatch, tier):
+    """A backend whose ``prepare_gather`` runs the planted batch through
+    the full-pool runner or the cone runner, and that runner's expected
+    one-shot result: ``make_solve_step``'s status and assignment, the
+    latter in the full var space the harvest reads."""
+    import jax.numpy as jnp
+
+    from mythril_tpu.ops import batched_sat as BS
+
+    lits, assign = _planted_batch()
+    backend = BS.BatchedSatBackend()
+    ctx = _Ctx()
+    if tier == "pool":
+        monkeypatch.setattr(backend, "_gather_eligibility",
+                            lambda ctx: (None, V))
+        monkeypatch.setattr(backend, "_sync_pool_and_assign",
+                            lambda ctx, sets, num_vars: assign)
+        backend.pool.num_vars, backend.pool.lits = V, jnp.asarray(lits)
+        full, status = BS.make_solve_step(V)(lits, assign)
+        status, full = np.asarray(status), np.asarray(full)
+    else:
+        cone_vars = np.arange(V) + 9  # compact column j + 1 is var j + 9
+        monkeypatch.setattr(backend, "_gather_eligibility",
+                            lambda ctx: ("size_bailouts", V))
+        monkeypatch.setattr(backend, "_build_cone_batch",
+                            lambda ctx, sets: (lits, assign, cone_vars, ()))
+        bucket = BS.DevicePool._bucket(V)
+        padded = np.ones((16, bucket + 1), np.int8)
+        padded[:, :V + 1] = assign
+        compact, status = BS.make_solve_step(bucket)(lits, padded)
+        status = np.asarray(status)
+        full = np.zeros((16, V + 10), np.int8)
+        full[:, cone_vars] = np.asarray(compact)[:, 1:V + 1]
+    monkeypatch.setattr(BS, "_env_from_assignment", lambda ctx, row: {})
+    return backend, ctx, status, full
+
+
+def _launch(dispatcher, backend, ctx):
+    lanes = range(16)
+    assert dispatcher.launch(backend, ctx, [[lane + 2] for lane in lanes],
+                             [[lane] for lane in lanes],
+                             [[] for _ in lanes])
+
+
+@pytest.mark.parametrize("tier", ["pool", "cone"])
+def test_sliced_prefetch_is_bit_identical_to_the_one_shot_solve(
+        monkeypatch, tier):
+    """Run to its last round, the prefetch's ladder of 64-step rounds
+    gives ``make_solve_step``'s statuses and assignments, bit for bit,
+    on lanes that end SAT, UNSAT and undecided."""
+    from mythril_tpu.ops.async_dispatch import AsyncDispatcher, async_stats
+    from mythril_tpu.ops.batched_sat import GATHER_ROUND_BUDGETS, GATHER_STEPS
+
+    backend, ctx, status, full = _prefetch(monkeypatch, tier)
+    assert {0, 1, 2} <= set(status.tolist())
+    dispatcher = AsyncDispatcher()
+    _launch(dispatcher, backend, ctx)
+    dispatcher._live_thread.join(timeout=120)
+    assert not dispatcher._live_thread.is_alive()
+    pending = dispatcher.pending
+    assert pending["done"] and not pending.get("failed")
+    assert async_stats.rounds == GATHER_STEPS // GATHER_ROUND_BUDGETS[0]
+    assert async_stats.preempted == 0
+    np.testing.assert_array_equal(pending["status"], status)
+    np.testing.assert_array_equal(pending["assign"], full)
+
+
+@pytest.mark.parametrize("waiter", ["analysis_thread", "none",
+                                    "worker_thread"])
+def test_a_device_wait_cuts_the_prefetch_at_its_next_round(monkeypatch,
+                                                           waiter):
+    """A ``device.wait`` entered on the analysis thread while the
+    worker runs round 2 stops the worker after that round: one batch
+    preempted, counted on the launching analysis's record, and the
+    harvest takes only the lanes two rounds decided.  With no wait, or
+    a wait on the worker's own thread, every round runs and the harvest
+    is the one-shot solve's."""
+    import threading
+
+    from mythril_tpu.observability import spans
+    from mythril_tpu.ops.async_dispatch import AsyncDispatcher, async_stats
+    from mythril_tpu.ops.batched_sat import GATHER_ROUND_BUDGETS, GATHER_STEPS
+
+    backend, ctx, status, _full = _prefetch(monkeypatch, "pool")
+    # the statuses after each round, from an unpreempted ladder
+    per_round = [np.asarray(h["status"]) for h in backend._prefetch_rounds(
+        V, backend.pool.lits, _planted_batch()[1])]
+    at_cut = np.where(per_round[1] == 3, 0, per_round[1])
+    decided = set(np.nonzero(status)[0])
+    assert set(np.nonzero(at_cut)[0]) < decided  # the cut costs lanes
+
+    in_round_2, go = threading.Event(), threading.Event()
+    real = backend._cached_round
+    calls = []
+
+    def gated(bucket, budget):
+        step = real(bucket, budget)
+
+        def run(*state):
+            calls.append(None)
+            if len(calls) == 2:
+                in_round_2.set()
+                assert go.wait(timeout=60)
+                if waiter == "worker_thread":
+                    with spans.wait("step_lock"):
+                        pass
+            return step(*state)
+
+        return run
+
+    monkeypatch.setattr(backend, "_cached_round", gated)
+    dispatcher = AsyncDispatcher()
+    with spans.analysis("cli.analyze"):
+        _launch(dispatcher, backend, ctx)
+        assert in_round_2.wait(timeout=120)
+        if waiter == "analysis_thread":
+            with spans.wait("replay.upload"):
+                pass
+        go.set()
+        dispatcher._live_thread.join(timeout=120)
+        assert not dispatcher._live_thread.is_alive()
+    record = spans.analysis_profiles()[-1]
+    dispatcher.harvest(ctx)
+    assert async_stats.harvested == 1
+
+    cut = waiter == "analysis_thread"
+    expected = at_cut if cut else status
+    assert async_stats.preempted == record["prefetch_preempted"] == cut
+    assert async_stats.rounds == (
+        2 if cut else GATHER_STEPS // GATHER_ROUND_BUDGETS[0])
+    assert ctx.unsat_lanes == np.nonzero(expected == 2)[0].tolist()
+    assert len(ctx.models) == int((expected == 1).sum())

@@ -169,9 +169,14 @@ def test_prefetch_is_credited_to_the_launching_analysis():
         def block_until_ready(self):
             release.wait(timeout=30)
 
+        def __array__(self, dtype=None, copy=None):
+            return np.ones(1, np.int32)
+
     class Backend:
         def prepare_gather(self, ctx, sets):
-            return lambda: {"status": Kernel(), "assign": np.zeros(1)}
+            return lambda: iter([{"status": Kernel(),
+                                  "assign": np.zeros((1, 2), np.int8),
+                                  "last": True}])
 
     class Ctx:
         generation = 0
@@ -192,6 +197,23 @@ def test_prefetch_is_credited_to_the_launching_analysis():
     for record in (first, second):
         assert not any(name.startswith("prefetch.")
                        for name in record["self_s"])
+
+
+def test_wait_hook_runs_as_each_device_wait_opens(monkeypatch):
+    """The hook the async prefetch sets runs on the waiting thread as a
+    ``device.wait`` span opens, tracing off, and for no other span."""
+    monkeypatch.setenv("MYTHRIL_TPU_TRACE", "0")
+    calls = []
+    monkeypatch.setattr(spans, "_wait_hook", None)
+    spans.set_wait_hook(lambda: calls.append(threading.get_ident()))
+    with spans.span("dispatch.batch_check"):
+        assert calls == []
+        with spans.wait("replay.upload"):
+            assert calls == [threading.get_ident()]
+    spans.set_wait_hook(None)
+    with spans.wait("replay.fetch"):
+        pass
+    assert len(calls) == 1
 
 
 def test_prefetch_counters_land_at_the_launch(monkeypatch):
@@ -424,12 +446,13 @@ def test_each_kernel_lowers_to_its_own_module(build, module):
 
 READERS = ("svm_self_s_per_analysis", "host_wait_s_per_analysis",
            "prefetch_inflight_s_per_analysis", "unspanned_s_per_analysis",
-           "setup_compile_s")
+           "setup_compile_s", "prefetch_preempted_per_analysis")
 
 
 def _record(wall, **fields):
     record = {"name": "cli.analyze", "wall_s": wall, "compile_s": 0.0,
-              "prefetch_inflight_s": 0.0, "wait_s": {}, "fuse_skips": 0,
+              "prefetch_inflight_s": 0.0, "prefetch_preempted": 0,
+              "wait_s": {}, "fuse_skips": 0,
               "self_s": {"cli.analyze": 0.01, "svm.round": 0.5,
                          "svm.transaction": 0.1, "device.wait": 0.2}}
     record.update(fields)
@@ -478,7 +501,7 @@ def test_reader_returns_none_with_fewer_records(readers, records, name):
 
 def test_readers_read_the_window_and_the_warmup(readers, records):
     records += [_record(40.0, compile_s=30.0),
-                _record(1.0, prefetch_inflight_s=0.9),
+                _record(1.0, prefetch_inflight_s=0.9, prefetch_preempted=1),
                 _record(3.0, prefetch_inflight_s=2.9,
                         self_s={"cli.analyze": 0.03, "svm.round": 1.0,
                                 "device.wait": 1.5})]
@@ -489,6 +512,8 @@ def test_readers_read_the_window_and_the_warmup(readers, records):
         pytest.approx(1.9))
     assert readers["unspanned_s_per_analysis"](run) == pytest.approx(0.02)
     assert readers["setup_compile_s"](run) == pytest.approx(30.0)
+    assert readers["prefetch_preempted_per_analysis"](run) == (
+        pytest.approx(0.5))
 
 
 def test_readers_find_nothing_in_a_program_without_records(readers,
