@@ -198,6 +198,7 @@ class AnalysisRecord:
             "prefetch_inflight_s": self._prefetch_s(),
             "prefetch_preempted": self.prefetch_preempted,
             "fuse_skips": self.counts.get("fuse_skips", 0),
+            "resident_iterations": self.counts.get("resident_iterations", 0),
             "spans": self.spans,
         }
 

@@ -252,6 +252,10 @@ class DispatchStats:
         self.resident_exit_all_decided = 0
         self.resident_exit_budget = 0
         self.resident_exit_watchdog = 0
+        # in-kernel loop iterations over those dispatches (the ``itc``
+        # each returns): the search's length, so device seconds over it
+        # is the cost of one iteration
+        self.resident_iterations = 0
         # dense dispatches the Pallas tier declined in favor of the
         # resident kernel (satellite: both ladders share one state
         # layout) — explains a dense-tier quiet round under the
@@ -1186,6 +1190,8 @@ class BatchedSatBackend:
             reason = RK.exit_reason(status, stall, iterations,
                                     watchdog_limit, budget)
             dispatch_stats.resident_dispatches += 1
+            dispatch_stats.resident_iterations += iterations
+            obs.record_count("resident_iterations", iterations)
             counter = f"resident_exit_{reason}"
             setattr(dispatch_stats, counter,
                     getattr(dispatch_stats, counter) + 1)

@@ -9,8 +9,10 @@ get their throughput from keeping the entire propagate->decide->learn
 loop resident in hardware.  This module is that design for XLA: one
 ``lax.while_loop`` dispatch that runs until a terminal condition the
 DEVICE decides, built from the same pieces as the frontier round —
-:func:`ops.frontier.make_scan_rows` is shared verbatim so the BCP/
-conflict semantics of the two kernels can never drift.
+:func:`ops.frontier.make_scan_rows` is shared verbatim for frontier
+steps and the extra pool, and the full sweep (:func:`make_count_scan`)
+returns its outputs exactly, so the BCP/conflict semantics of the two
+kernels can never drift.
 
 What moves into the kernel:
 
@@ -193,6 +195,99 @@ def exit_reason(status: np.ndarray, stall: int, itc: int,
     return "budget"
 
 
+def make_count_scan(V1: int):
+    """The resident kernel's full sweep as occurrence-count matmuls:
+    ``(count_lits, count_scan)``.
+
+    Within one dispatch the clause rows never change, so
+    ``count_lits(lits[C, K])`` builds once, outside the loop,
+    ``occ int8[C, 2*V1]`` (column ``v`` counts the occurrences of
+    ``+v`` in a row, column ``V1 + v`` those of ``-v``) and
+    ``real[C]`` (rows holding a literal).  Counts, not incidence, so a
+    row with a duplicate literal or with both ``v`` and ``-v`` keeps
+    the unknown count a per-literal scan gives it.
+
+    ``count_scan((occ, real), assign[B, V1])`` then returns exactly
+    what :func:`ops.frontier.make_scan_rows`' scan returns over every
+    row with ``scores=True``, from two int8 MXU matmuls and one B·C
+    scatter (the reason rows) in place of a B·C·K gather and six
+    scatters.  Per row it counts the true, the unknown and the unknown
+    positive occurrences, and sums the unknown occurrences' var ids in
+    7-bit digits (an int8 operand holds each digit exactly): in a unit
+    row that sum is the one unknown var.  The DLIS scores project the
+    open rows back onto their literals, masked to unknown vars.
+    Memory: ``C * 2 * V1`` bytes, 268 MB at the cone caps.
+    """
+    from mythril_tpu.ops.batched_sat import _require_jax
+
+    _, jnp = _require_jax()
+    from jax import lax
+
+    digit_weights = [1]
+    while digit_weights[-1] * 128 < V1:
+        digit_weights.append(digit_weights[-1] * 128)
+
+    def count_lits(lits):
+        C, K = lits.shape
+        lit_col = jnp.where(lits < 0, V1 - lits, lits)
+        row = lax.broadcasted_iota(jnp.int32, (C, K), 0)
+        real_lit = lits != 0
+        occ = jnp.zeros((C, 2 * V1), jnp.int8).at[row, lit_col].add(
+            real_lit.astype(jnp.int8)
+        )
+        return occ, jnp.any(real_lit, axis=1)
+
+    def count_scan(counted, assign):
+        occ, real = counted
+        B = assign.shape[0]
+        C = occ.shape[0]
+        true, false, unk = assign == 1, assign == -1, assign == 0
+        var = lax.broadcasted_iota(jnp.int32, (B, V1), 1)
+
+        def lit_cols(pos, neg):
+            return jnp.concatenate([pos, neg], axis=1).astype(jnp.int8)
+
+        lhs = [lit_cols(true, false), lit_cols(unk, unk),
+               lit_cols(unk, jnp.zeros_like(unk))]
+        for w in digit_weights:
+            digit = jnp.where(unk, (var // w) % 128, 0)
+            lhs.append(lit_cols(digit, digit))
+        per_row = lax.dot_general(
+            jnp.concatenate(lhs, axis=0), occ, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        ).reshape(len(lhs), B, C)
+        n_true, n_unknown, n_unknown_pos = per_row[0], per_row[1], per_row[2]
+        unknown_var = sum(per_row[3 + i] * w
+                          for i, w in enumerate(digit_weights))
+        unsat_yet = (n_true == 0) & real[None, :]
+        all_false = unsat_yet & (n_unknown == 0)
+        unit = unsat_yet & (n_unknown == 1)
+        row_id1 = lax.broadcasted_iota(jnp.int32, (B, C), 1) + 1
+        forced_col = jnp.where(
+            unit,
+            jnp.where(n_unknown_pos == 1, unknown_var, V1 + unknown_var),
+            0,
+        )
+        bc = lax.broadcasted_iota(jnp.int32, (B, C), 0)
+        reasons = jnp.zeros((B, 2 * V1), jnp.int32).at[bc, forced_col].max(
+            jnp.where(unit, row_id1, 0)
+        )
+        rpos, rneg = reasons[:, :V1], reasons[:, V1:]
+        conflict = jnp.any(all_false, axis=1)
+        conflict_row = jnp.max(jnp.where(all_false, row_id1, 0), axis=1) - 1
+        is_open = unsat_yet & (n_unknown > 1)
+        score = lax.dot_general(
+            is_open.astype(jnp.int8), occ, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
+        return ((rpos > 0).astype(jnp.int32), (rneg > 0).astype(jnp.int32),
+                rpos, rneg, conflict, conflict_row,
+                jnp.where(unk, score[:, :V1], 0),
+                jnp.where(unk, score[:, V1:], 0))
+
+    return count_lits, count_scan
+
+
 def build_resident_rounds(num_vars: int, budget: int,
                           max_decisions: int, fan: int, period: int,
                           watchdog: int, extra_cap: int,
@@ -222,6 +317,7 @@ def build_resident_rounds(num_vars: int, budget: int,
     fan = max(1, min(fan, V1))  # top_k cannot exceed the var axis
     E = extra_cap
     scan_rows = make_scan_rows(V1)
+    count_lits, count_scan = make_count_scan(V1)
 
     # jitted as the XLA module jit_rounds; the frontier and dense round
     # kernels have names of their own, so jit_rounds in a profile is
@@ -259,14 +355,13 @@ def build_resident_rounds(num_vars: int, budget: int,
                     jnp.maximum(rp1, rp2), jnp.maximum(rn1, rn2),
                     c1 | c2, jnp.maximum(cr1, cr2), sp1, sn1)
 
+        # the pool rows' occurrence counts, once per dispatch: every
+        # full sweep of the loop reads them (see make_count_scan)
+        counted = count_lits(lits)
+
         def full_scan(assign, extra, nextra):
-            rows = jnp.broadcast_to(lits[None], (B, C, K))
-            row_ids = jnp.broadcast_to(
-                jnp.arange(C, dtype=jnp.int32)[None], (B, C)
-            )
-            pool_res = scan_rows(rows, row_ids, jnp.ones((B, C), bool),
-                                 assign, True)
-            return merge(pool_res, extra_scan(assign, extra, nextra))
+            return merge(count_scan(counted, assign),
+                         extra_scan(assign, extra, nextra))
 
         def frontier_scan(assign, recent, extra, nextra):
             pri = jnp.where(recent, col, 0)

@@ -490,6 +490,172 @@ def test_funnel_conserves_lanes_under_resident(monkeypatch):
         ledger.reset_for_tests()
 
 
+def test_resident_iterations_sum_each_dispatch_itc(monkeypatch):
+    """``dispatch_stats.resident_iterations`` is the sum of the ``itc``
+    each resident dispatch returns, the analysis record carries the
+    same sum, and the count restarts with ``dispatch_stats``."""
+    from mythril_tpu.observability import spans
+
+    seen = []
+    exit_reason = RK.exit_reason
+
+    def spy(status, stall, itc, watchdog, budget):
+        seen.append(itc)
+        return exit_reason(status, stall, itc, watchdog, budget)
+
+    monkeypatch.setattr(RK, "exit_reason", spy)
+    backend = BatchedSatBackend()
+    with spans.analysis("cli.analyze"):
+        for clauses in ([[1], [2, 3], [-2, 4]],
+                        [[1], [-2, 3], [2, 3], [-3, -2]]):
+            assign = np.zeros((2, 5), np.int8)
+            assign[:, 1] = 1
+            _solve(backend, _rows(clauses), assign)
+    assert len(seen) == 2 and min(seen) > 0
+    assert dispatch_stats.resident_iterations == sum(seen)
+    assert spans.analysis_profiles()[-1]["resident_iterations"] == sum(seen)
+    dispatch_stats.reset()
+    assert dispatch_stats.resident_iterations == 0
+
+
+# ------------------------------- the full sweep as count matmuls
+
+
+def _sweep_case(seed):
+    """Random clause rows for one sweep: zero padding rows, widths 1-8
+    with literals in random slots, duplicate literals, ``v ∨ ¬v`` rows,
+    the TRUE anchor (var 1), and lanes from nearly complete (units,
+    conflicts) to nearly empty.  Row 0 is made a unit in lane 1 and a
+    conflict in lane 2; V1 past 128 takes a second var-id digit."""
+    rng = np.random.default_rng(seed)
+    V1 = int(rng.choice([9, 40, 130, 300]))
+    C, B = 96, 6
+    rows = np.zeros((C, K), np.int32)
+    for r in range(C - 16):  # the last 16 rows stay padding
+        width = int(rng.integers(1, K + 1))
+        lits = (rng.integers(1, V1, size=width)
+                * rng.choice([1, -1], size=width))
+        if width > 1 and rng.random() < 0.2:
+            lits[1] = lits[0]                # duplicate literal
+        if width > 1 and rng.random() < 0.2:
+            lits[-1] = -lits[0]              # tautology
+        if r == 0:
+            lits = np.array([2, -3, 4][: min(3, V1 - 2)])
+        rows[r, rng.choice(K, size=len(lits), replace=False)] = lits
+    rng.shuffle(rows[1:])
+    assign = np.zeros((B, V1), np.int8)
+    for lane in range(B):
+        known = rng.random(V1) < (0.95 - 0.15 * lane)
+        assign[lane] = np.where(known, rng.choice([1, -1], size=V1), 0)
+    assign[:, 1] = 1                         # the TRUE anchor
+    row0 = rows[0][rows[0] != 0]
+    assign[1:3, np.abs(row0)] = -np.sign(row0)   # every literal false
+    assign[1, abs(row0[0])] = 0                  # ... but one: a unit
+    return rows, assign, V1
+
+
+def _gather_count_scan(V1):
+    """The reference full sweep: ``make_scan_rows`` over every row."""
+    import jax.numpy as jnp
+
+    from mythril_tpu.ops.frontier import make_scan_rows
+
+    scan_rows = make_scan_rows(V1)
+
+    def count_scan(lits, assign):
+        B = assign.shape[0]
+        C, width = lits.shape
+        return scan_rows(
+            jnp.broadcast_to(lits[None], (B, C, width)),
+            jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[None], (B, C)),
+            jnp.ones((B, C), bool), assign, True)
+
+    return (lambda lits: lits), count_scan
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_count_sweep_matches_the_gather_scan(seed):
+    """The resident kernel's full sweep (occurrence-count matmuls over
+    the cone) returns all eight outputs of ``make_scan_rows`` with
+    ``scores=True`` over every row, value for value and dtype for
+    dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    rows, assign, V1 = _sweep_case(seed)
+    sweeps = []
+    for count_lits, count_scan in (RK.make_count_scan(V1),
+                                   _gather_count_scan(V1)):
+        sweep = jax.jit(lambda lits, a: count_scan(count_lits(lits), a))
+        sweeps.append(sweep(jnp.asarray(rows), jnp.asarray(assign)))
+    got, want = sweeps
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    fpos, fneg, _, _, conflict, _, spos, sneg = map(np.asarray, want)
+    assert conflict[2]
+    assert (fpos[1] | fneg[1]).any() and (spos + sneg).any()
+
+
+def _guard_cone():
+    """The cone of one ``tree16`` guard, blasted: ``(x & 0xffff) +
+    addend & 0xffff == target`` on a 256-bit calldata word."""
+    from mythril_tpu.smt import terms as T
+    from mythril_tpu.smt.bitblast import BlastContext
+
+    x = T.var("tree16_guard_x", 256)
+    mask = T.const(0xFFFF, 256)
+    guard = T.eq(T.band(T.add(T.band(x, mask), T.const(0x3A7C, 256)),
+                        mask), T.const(0x91E5, 256))
+    ctx = BlastContext()
+    root = ctx.blast_lit(guard)
+    clause_ids, _ = ctx.cone([root])
+    clauses = [list(ctx.clauses_py[i]) for i in clause_ids]
+    clauses = [c for c in clauses if len(c) <= K] + [[1], [root]]
+    used = sorted({abs(l) for c in clauses for l in c} - {1})
+    dense = {1: 1, **{v: i + 2 for i, v in enumerate(used)}}
+    clauses = [[dense[abs(l)] * (1 if l > 0 else -1) for l in c]
+               for c in clauses]
+    return clauses, len(used) + 2
+
+
+def _kernel_case(case):
+    """(clauses, assign) for the kernel-parity cases: random CNFs under
+    assumption lanes, or the guard's cone with lanes that pin some of
+    the word's low bits either way."""
+    rng = np.random.default_rng(300 if case == "guard" else 301 + case)
+    if case == "guard":
+        clauses, V1 = _guard_cone()
+        assign = np.zeros((6, V1), np.int8)
+        for lane in range(1, 6):
+            pinned = rng.choice(np.arange(2, V1), size=3, replace=False)
+            assign[lane, pinned] = rng.choice([1, -1], size=3)
+    else:
+        nv = 10
+        clauses = _random_instance(rng, nv, int(rng.integers(18, 40)))
+        V1 = nv + 1
+        assign = np.zeros((4, V1), np.int8)
+        for lane in range(1, 4):
+            assign[lane, 2 + lane] = 1 if lane % 2 else -1
+    assign[:, 1] = 1
+    return clauses, assign
+
+
+@pytest.mark.parametrize("case", [0, 1, 2, 3, "guard"])
+def test_kernel_matches_the_gather_sweep_kernel(case, monkeypatch):
+    """One resident dispatch with the count-matmul full sweep leaves
+    the whole state — status, trail, reasons, learned rows, the extra
+    pool, sweep counts and the iteration count — equal to the same
+    kernel built with the gather sweep of ``make_scan_rows``."""
+    clauses, assign = _kernel_case(case)
+    got = _run_kernel(clauses, assign)
+    monkeypatch.setattr(RK, "make_count_scan", _gather_count_scan)
+    want = _run_kernel(clauses, assign)
+    for key in RK.RESIDENT_STATE_FIELDS:
+        np.testing.assert_array_equal(got[key], want[key], err_msg=key)
+    assert int(got["itc"][0]) > 0 and got["fullsw"].any()
+
+
 # ------------------------------------------------- env knob surface
 
 

@@ -446,13 +446,14 @@ def test_each_kernel_lowers_to_its_own_module(build, module):
 
 READERS = ("svm_self_s_per_analysis", "host_wait_s_per_analysis",
            "prefetch_inflight_s_per_analysis", "unspanned_s_per_analysis",
-           "setup_compile_s", "prefetch_preempted_per_analysis")
+           "setup_compile_s", "prefetch_preempted_per_analysis",
+           "resident_iterations_per_analysis")
 
 
 def _record(wall, **fields):
     record = {"name": "cli.analyze", "wall_s": wall, "compile_s": 0.0,
               "prefetch_inflight_s": 0.0, "prefetch_preempted": 0,
-              "wait_s": {}, "fuse_skips": 0,
+              "wait_s": {}, "fuse_skips": 0, "resident_iterations": 0,
               "self_s": {"cli.analyze": 0.01, "svm.round": 0.5,
                          "svm.transaction": 0.1, "device.wait": 0.2}}
     record.update(fields)
@@ -501,8 +502,9 @@ def test_reader_returns_none_with_fewer_records(readers, records, name):
 
 def test_readers_read_the_window_and_the_warmup(readers, records):
     records += [_record(40.0, compile_s=30.0),
-                _record(1.0, prefetch_inflight_s=0.9, prefetch_preempted=1),
-                _record(3.0, prefetch_inflight_s=2.9,
+                _record(1.0, prefetch_inflight_s=0.9, prefetch_preempted=1,
+                        resident_iterations=1537),
+                _record(3.0, prefetch_inflight_s=2.9, resident_iterations=771,
                         self_s={"cli.analyze": 0.03, "svm.round": 1.0,
                                 "device.wait": 1.5})]
     run = _Run([1.0, 3.0])
@@ -514,6 +516,8 @@ def test_readers_read_the_window_and_the_warmup(readers, records):
     assert readers["setup_compile_s"](run) == pytest.approx(30.0)
     assert readers["prefetch_preempted_per_analysis"](run) == (
         pytest.approx(0.5))
+    assert readers["resident_iterations_per_analysis"](run) == (
+        pytest.approx(1154.0))
 
 
 def test_readers_find_nothing_in_a_program_without_records(readers,
